@@ -268,7 +268,7 @@ def load_checkpoint(path: str, target_tree=None, shardings=None,
     manifest = None
     buffers: Dict[int, np.ndarray] = {}
     covered: Dict[int, int] = {}
-    pending: List[Tuple[int, tuple, int, int, bytes]] = []
+    pending: List[Tuple[int, tuple, int, int, np.ndarray]] = []
 
     def _apply(pid, shape, r0, r1, data):
         npdt = _np_dtype(manifest["dtypes"][pid])
@@ -277,7 +277,7 @@ def load_checkpoint(path: str, target_tree=None, shardings=None,
             # must read as a defined value, not heap garbage
             alloc = np.empty if strict else np.zeros
             buffers[pid] = alloc(shape, npdt)
-        piece = np.frombuffer(data, npdt)
+        piece = data.view(npdt)
         if buffers[pid].ndim:
             buffers[pid][r0:r1] = piece.reshape((r1 - r0,) + shape[1:])
             covered[pid] = covered.get(pid, 0) + (r1 - r0)
@@ -285,18 +285,27 @@ def load_checkpoint(path: str, target_tree=None, shardings=None,
             buffers[pid] = piece.reshape(()).copy()
             covered[pid] = 1
 
+    # column-at-a-time: each entry's payload is a zero-copy slice of the
+    # cluster's decoded data column (no per-byte entry recomposition)
+    col = [reader.schema.column_of_path[p] for p in (
+        "param_id", "shape", "shape._0", "row_start", "row_end",
+        "data", "data._0")]
     for ci in range(reader.n_clusters):
-        for e in reader.iter_cluster_entries(ci):
-            pid = int(e["param_id"])
-            data = np.asarray(e["data"], np.uint8).tobytes()
+        c = reader.read_cluster(ci, col)
+        pids, s_end, s_val, r0s, r1s, d_end, d_val = (c[i] for i in col)
+        s_beg = np.concatenate([[0], s_end[:-1]])
+        d_beg = np.concatenate([[0], d_end[:-1]])
+        for k in range(len(pids)):
+            pid = int(pids[k])
+            data = d_val[d_beg[k]:d_end[k]]
             if pid == -1:
-                manifest = json.loads(data)
+                manifest = json.loads(data.tobytes())
                 for args in pending:
                     _apply(*args)
                 pending = []
                 continue
-            shape = tuple(int(s) for s in e["shape"])
-            r0, r1 = int(e["row_start"]), int(e["row_end"])
+            shape = tuple(s_val[s_beg[k]:s_end[k]].tolist())
+            r0, r1 = int(r0s[k]), int(r1s[k])
             if manifest is None:
                 pending.append((pid, shape, r0, r1, data))
             else:

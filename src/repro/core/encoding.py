@@ -6,7 +6,7 @@ columns.  Preconditioning radically improves the entropy coder's ratio on
 monotonic offset columns and on floats with correlated exponents.
 
 The numpy functions here are the canonical host implementations; the Pallas
-kernels in ``repro.kernels.{byteshuffle,delta_zigzag,offsets_scan}`` are the
+kernels in ``repro.kernels.{byteshuffle,offsets_scan}`` are the
 TPU-side ports and are property-tested to be bit-identical against these
 (via ``repro.kernels.ref`` which re-exports the same math in jnp).
 """
@@ -183,9 +183,9 @@ def _batched_split_into(a: np.ndarray, per: int, out_u8: np.ndarray) -> None:
     :func:`split_encode` page by page, but the full pages go through one
     batched strided copy instead of a Python loop.  Large columns
     dispatch the full-pages block to the Pallas ``byteshuffle`` kernel
-    when an accelerator backend is available (see
-    :func:`_resolve_pallas_shuffle`); the strided numpy copy is the
-    fallback and the reference.
+    when an accelerator backend is available (``BYTESHUFFLE``); the strided
+    numpy copy is the host path and the reference.  A kernel failure
+    raises; it is never replaced by numpy.
     """
     if a.dtype.byteorder == ">":
         a = a.astype(a.dtype.newbyteorder("<"))
@@ -195,20 +195,11 @@ def _batched_split_into(a: np.ndarray, per: int, out_u8: np.ndarray) -> None:
     head = n_full * per
     if n_full:
         src = a[:head].view(np.uint8).reshape(n_full, per, nb)
-        done = False
-        if _SHUFFLE.want(head * nb):
-            kernel = _SHUFFLE.resolve()
-            if kernel:
-                try:
-                    out_u8[: head * nb].reshape(n_full, nb, per)[:] = kernel(src)
-                    done = True
-                except Exception:
-                    _SHUFFLE.disable()
-        if not done:
-            np.copyto(
-                out_u8[: head * nb].reshape(n_full, nb, per),
-                src.transpose(0, 2, 1),
-            )
+        dst = out_u8[: head * nb].reshape(n_full, nb, per)
+        if BYTESHUFFLE.want(head * nb):
+            dst[:] = BYTESHUFFLE.run(src)
+        else:
+            np.copyto(dst, src.transpose(0, 2, 1))
     if head < n:
         _split_into(a[head:], out_u8[head * nb :])
 
@@ -413,9 +404,9 @@ def unprecondition(buf: bytes, encoding: str, dtype: np.dtype, n: int) -> np.nda
 # KernelDispatch (repro.kernels.ops).  REPRO_KERNEL_BACKEND sets the global
 # default; REPRO_OFFSETS_BACKEND / REPRO_SHUFFLE_BACKEND stay honored as
 # per-kernel overrides, with REPRO_*_PALLAS_MIN size floors below which the
-# numpy path always wins.  "auto" only selects a kernel on an accelerator
+# numpy path is kept.  "auto" only selects a kernel on an accelerator
 # backend with jax already imported — the CPU interpret path exists for
-# correctness tests, not speed.
+# correctness tests, not speed.  A kernel that fails raises.
 
 
 def _load_offsets_kernel():
@@ -431,9 +422,9 @@ def _load_shuffle_kernel():
 
 
 #: offsets-scan dispatch; ``min`` is in ELEMENTS
-_OFFSETS = KernelDispatch("offsets", _load_offsets_kernel, min_default=65536)
+OFFSETS_SCAN = KernelDispatch("offsets", _load_offsets_kernel, min_default=65536)
 #: byteshuffle dispatch; ``min`` is in BYTES
-_SHUFFLE = KernelDispatch("shuffle", _load_shuffle_kernel,
+BYTESHUFFLE = KernelDispatch("shuffle", _load_shuffle_kernel,
                           min_default=256 * 1024)
 
 
@@ -446,22 +437,17 @@ def integrate_sizes(
     reserved tail of an offset :class:`~repro.core.colbuf.ColumnBuffer`).
     Large columns dispatch to the Pallas ``offsets_scan`` kernel when an
     accelerator backend is available (or ``REPRO_OFFSETS_BACKEND=pallas``
-    forces it); the numpy inclusive scan is the fallback and the reference.
+    forces it); the numpy inclusive scan is the host path and the
+    reference.  A kernel failure raises; it is never replaced by numpy.
     """
     n = len(sizes)
     if out is None:
         out = np.empty(n, dtype=np.int64)
-    done = False
-    if n and _OFFSETS.want(n):
-        kernel = _OFFSETS.resolve()
-        # the kernel scans in int32: only dispatch when the total fits
-        if kernel and int(np.sum(sizes, dtype=np.int64)) < 2**31:
-            try:
-                out[:] = kernel(np.asarray(sizes))
-                done = True
-            except Exception:
-                _OFFSETS.disable()
-    if not done:
+    # the kernel scans in int32: only dispatch when the total fits
+    if (n and OFFSETS_SCAN.want(n)
+            and int(np.sum(sizes, dtype=np.int64)) < 2**31):
+        out[:] = OFFSETS_SCAN.run(np.asarray(sizes))
+    else:
         np.cumsum(
             np.asarray(sizes).astype(np.int64, copy=False),
             dtype=np.int64, out=out,

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -47,7 +46,8 @@ def pipelined(
         m = n_microbatches
         mb = x_stage.reshape((m, x_stage.shape[0] // m) + x_stage.shape[1:])
         n_ticks = m + n_stages - 1
-        outputs = jnp.zeros_like(mb)
+        # the carries differ per stage: type them as varying over the axis
+        outputs = lax.pcast(jnp.zeros_like(mb), stage_axis, to="varying")
 
         def run_layers(x):
             def body(x, lp):
@@ -79,22 +79,23 @@ def pipelined(
             )
             return (nxt, outputs), None
 
-        buf0 = jnp.zeros_like(mb[0])
+        buf0 = lax.pcast(jnp.zeros_like(mb[0]), stage_axis, to="varying")
         (_, outputs), _ = lax.scan(tick, (buf0, outputs),
                                    jnp.arange(n_ticks))
-        # stack per-stage results; only the last stage's slot is real
-        return outputs.reshape(x_stage.shape)[None]
+        # only the last stage's outputs are real: broadcast them to every
+        # stage so the result is replicated over the stage axis
+        last = jnp.where(my_stage == n_stages - 1, outputs, 0)
+        return lax.psum(last, stage_axis).reshape(x_stage.shape)
 
     def apply(stacked_params, x):
         param_specs = jax.tree_util.tree_map(
             lambda _: P(stage_axis), stacked_params)
-        fn = shard_map(
+        fn = jax.shard_map(
             stage_body, mesh=mesh,
             in_specs=(param_specs, P()),
-            out_specs=P(stage_axis),
-            check_rep=False,
+            out_specs=P(),
         )
-        per_stage = fn(stacked_params, x)   # (n_stages, batch, ...)
-        return per_stage[-1]
+        with jax.set_mesh(mesh):
+            return fn(stacked_params, x)
 
     return apply

@@ -1,7 +1,7 @@
 """Pallas TPU kernels for the framework's compute hot spots.
 
 Columnar-encoding kernels (the paper's serialization path, DESIGN.md §3.3):
-``offsets_scan``, ``byteshuffle``, ``delta_zigzag`` — and the read-side
+``offsets_scan``, ``byteshuffle`` — and the read-side
 fused decode chain ``decode_pages`` (DESIGN.md §9).
 
 Model kernels: ``flash_attention``, ``decode_attention``, ``rwkv6_scan``,

@@ -1,7 +1,7 @@
 """Pallas TPU kernels: the fused per-column page *decode* chain.
 
 The read-side inverse of the write path's preconditioning kernels
-(``byteshuffle_pages``, ``delta_zigzag``, ``offsets_scan``): stored page
+(``byteshuffle_pages``, ``offsets_scan``): stored page
 bytes upload to the device ONCE and columns materialize directly as JAX
 device arrays — no host unsplit, no host zigzag/delta pass, no host
 offset integration (DESIGN.md §9).
@@ -15,7 +15,8 @@ Two kernels:
   uint64 zigzag deltas (the on-disk ``delta+zigzag+split`` encoding with
   per-page delta restart) decode in one pass to int32 cluster-relative
   end offsets: byte-plane gather -> zigzag inverse -> blocked inclusive
-  scan with an SMEM carry that resets at every page boundary.
+  scan (:func:`~repro.kernels.offsets_scan.block_scan`) with a VMEM
+  carry that resets at every page boundary.
 
 Both run in 32-bit lanes: the read engine only dispatches an offset
 column here when the cluster's element total is below 2**31 (known from
@@ -33,6 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .offsets_scan import DEFAULT_ROWS, LANES, block_scan
 
 DEFAULT_BLOCK = 2048
 
@@ -71,56 +74,56 @@ def unsplit_pages(
 
 
 def _offsets_decode_kernel(x_ref, o_ref, carry_ref):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _():
         # per-page delta restart: the carry resets at every page start
-        carry_ref[0] = jnp.zeros((), jnp.int32)
+        carry_ref[...] = jnp.zeros_like(carry_ref)
 
-    x = x_ref[...]  # (1, 8, BN) uint8 byte planes of the stored uint64
-    # low 32 bits only — the dispatch guard proves planes 4..7 are zero
-    z = (
-        x[0, 0].astype(jnp.uint32)
-        | (x[0, 1].astype(jnp.uint32) << 8)
-        | (x[0, 2].astype(jnp.uint32) << 16)
-        | (x[0, 3].astype(jnp.uint32) << 24)
-    )
-    # zigzag inverse: (z >> 1) ^ -(z & 1); the logical shift happens in
-    # uint32, the xor in int32 (magnitudes fit by the same guard)
-    d = (z >> 1).astype(jnp.int32) ^ -(z & 1).astype(jnp.int32)
-    o_ref[...] = (jnp.cumsum(d) + carry_ref[0])[None]
-    carry_ref[0] = carry_ref[0] + jnp.sum(d)
+    # (1, 4, R, 128) uint8: the low byte planes of the stored uint64 —
+    # the dispatch guard proves planes 4..7 are zero
+    z = x_ref[0, 0].astype(jnp.int32)
+    for k in range(1, 4):
+        z = z | (x_ref[0, k].astype(jnp.int32) << (8 * k))
+    # zigzag inverse: (z >>> 1) ^ -(z & 1), in int32 (magnitudes fit by
+    # the same guard)
+    d = jax.lax.shift_right_logical(z, 1) ^ -(z & 1)
+    o_ref[0], carry_ref[...] = block_scan(d, carry_ref[...])
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("rows", "interpret"))
 def decode_offset_pages(
-    planes: jax.Array, block: int = DEFAULT_BLOCK, interpret: bool = False
+    planes: jax.Array, rows: int = DEFAULT_ROWS, interpret: bool = False
 ) -> jax.Array:
     """(P, 8, per) uint8 split zigzag deltas -> (P, per) int32 end offsets.
 
     The fused offset-column decode: one kernel launch per column replaces
     the host's unsplit + zigzag decode + per-page ``integrate_sizes``
-    loop.  The grid walks (page, block-within-page); the scan carry lives
-    in SMEM and resets at each page's first block (per-page delta
-    restart), so pages integrate independently exactly like the numpy
-    reference.
+    loop.  Each page is laid out lane-dense as ``(rows, 128)`` tiles; the
+    grid walks (page, tile-within-page) and the scan carry resets at each
+    page's first tile (per-page delta restart), so pages integrate
+    independently exactly like the numpy reference.
     """
     n_pages, itemsize, per = planes.shape
     assert itemsize == 8, "offset columns store uint64 planes"
-    blk = min(block, per)
-    pad = (-per) % blk
+    # uint8 tiles are (32, 128): a block holds a multiple of 32 rows
+    rows = min(rows, -(-per // (32 * LANES)) * 32)
+    block = rows * LANES
+    pad = (-per) % block
     x = jnp.pad(planes, ((0, 0), (0, 0), (0, pad)))
+    x = x.reshape(n_pages, itemsize, -1, LANES)
     out = pl.pallas_call(
         _offsets_decode_kernel,
-        out_shape=jax.ShapeDtypeStruct((n_pages, x.shape[2]), jnp.int32),
-        grid=(n_pages, x.shape[2] // blk),
-        in_specs=[pl.BlockSpec((1, 8, blk), lambda i, j: (i, 0, j))],
-        out_specs=pl.BlockSpec((1, blk), lambda i, j: (i, j)),
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        out_shape=jax.ShapeDtypeStruct((n_pages,) + x.shape[2:], jnp.int32),
+        grid=(n_pages, x.shape[2] // rows),
+        in_specs=[pl.BlockSpec((1, 4, rows, LANES),
+                               lambda i, j: (i, 0, j, 0))],
+        out_specs=pl.BlockSpec((1, rows, LANES), lambda i, j: (i, j, 0)),
+        scratch_shapes=[pltpu.VMEM((rows, LANES), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x)
-    return out[:, :per]
+    return out.reshape(n_pages, -1)[:, :per]
 
 
 # ---------------------------------------------------------------------------

@@ -1,11 +1,14 @@
 """Public kernel entry points with backend dispatch.
 
-On TPU the Pallas kernels run compiled; everywhere else (this CPU
-container, tests) they run through ``interpret=True`` or fall back to the
-``ref`` oracles.  Model code calls these wrappers only.
+On TPU the Pallas kernels run compiled; on a CPU backend (tests) they run
+through ``interpret=True`` when forced, and the ``ref`` oracles compile
+through XLA otherwise.  Model code calls these wrappers only.
 
 ``use_pallas``: None = auto (pallas on TPU, ref elsewhere), True = force
-pallas (interpret on CPU), False = force ref.
+pallas (interpret on CPU), False = force ref.  One exception to auto:
+:func:`flash_attention` has no VJP, and the model code that calls it is
+differentiated by the train step, so its auto is the XLA attention (see
+there).
 
 This module also owns :class:`KernelDispatch` — the ONE auto/numpy/pallas
 backend selector shared by every host-facing encode/decode kernel
@@ -19,6 +22,7 @@ from __future__ import annotations
 
 import os
 import sys
+import threading
 from typing import Callable, Optional
 
 # ---------------------------------------------------------------------------
@@ -33,11 +37,10 @@ GLOBAL_BACKEND_ENV = "REPRO_KERNEL_BACKEND"
 class KernelDispatch:
     """auto / numpy / pallas backend selection for one kernel family.
 
-    Consolidates what used to be a per-kernel copy of the same logic in
-    ``core/encoding.py`` (ISSUE 7 satellite): environment resolution,
-    the "auto never pays a cold jax import on the hot path" rule, the
-    size floor below which the host fallback always wins, and the
-    rule-out-once-on-failure cache.
+    One copy of the selection logic for every host-facing encode/decode
+    kernel: environment resolution, the "auto never pays a cold jax
+    import on the hot path" rule, the size floor below which the host
+    path is kept, and the counter of calls that went to the kernel.
 
     Resolution order for the backend string:
 
@@ -45,74 +48,55 @@ class KernelDispatch:
     2. ``REPRO_KERNEL_BACKEND`` — the global default for all kernels;
     3. ``"auto"``.
 
-    ``auto`` selects the Pallas kernel only when jax is *already
-    imported* by the application (never pay a multi-second cold import
-    inside a seal or decode path) AND the default backend is an
-    accelerator; ``pallas`` forces the kernel (interpret mode on CPU —
-    the bit-identity test configuration); ``numpy`` pins the host
-    fallback.  The size floor ``REPRO_<NAME>_PALLAS_MIN`` (units chosen
-    by the call site: elements or bytes) only gates ``auto``.
+    ``auto`` selects the Pallas kernel for every call at or above the
+    size floor ``REPRO_<NAME>_PALLAS_MIN`` (units chosen by the call
+    site: elements or bytes) when jax is *already imported* by the
+    application (never pay a multi-second cold import inside a seal or
+    decode path) AND the default backend is an accelerator; ``pallas``
+    forces the kernel for every call (interpret mode on CPU — the
+    bit-identity test configuration); ``numpy`` pins the host path.
 
-    The instance is mutable on purpose: tests monkeypatch ``backend``
-    and reset ``_kernel`` to re-resolve under an override.
+    There is no fallback after a failure: a kernel that raises, raises
+    out of the call site.  ``calls`` counts the calls that ran the
+    kernel.  The instance is mutable on purpose: tests monkeypatch
+    ``backend`` and ``min``.
     """
 
-    def __init__(
-        self,
-        name: str,
-        loader: Callable[[], Callable],
-        min_default: int,
-        device_only: bool = True,
-    ) -> None:
+    BACKENDS = ("auto", "numpy", "pallas")
+
+    def __init__(self, name: str, loader: Callable[[], Callable],
+                 min_default: int) -> None:
         self.name = name
         env = f"REPRO_{name.upper()}_BACKEND"
         self.backend = os.environ.get(
             env, os.environ.get(GLOBAL_BACKEND_ENV, "auto")
         ).lower()
+        if self.backend not in self.BACKENDS:
+            raise ValueError(
+                f"{env}/{GLOBAL_BACKEND_ENV}={self.backend!r}: expected one "
+                f"of {self.BACKENDS}")
         self.min = int(
             os.environ.get(f"REPRO_{name.upper()}_PALLAS_MIN", str(min_default))
         )
+        self.calls = 0
+        self._lock = threading.Lock()
         self._loader = loader
-        self._device_only = device_only
-        self._kernel: Optional[Callable] = None  # None = unresolved; False = out
+        self._kernel: Optional[Callable] = None
 
     def want(self, measure: int) -> bool:
-        """Should this call even consider the kernel? (size gate)"""
+        """Does a call of size ``measure`` go to the kernel?"""
         if self.backend == "pallas":
             return True
-        return self.backend == "auto" and measure >= self.min
+        return (self.backend == "auto" and measure >= self.min
+                and _on_accelerator())
 
-    def resolve(self) -> Optional[Callable]:
-        """The kernel callable, or a falsy value when ruled out.
-
-        In ``auto`` mode a missing jax import stays *unresolved* (returns
-        ``False`` without caching the negative) so a later jax import can
-        still enable the kernel; a CPU-only jax backend rules the kernel
-        out for good (interpret mode exists for correctness tests, not
-        speed).
-        """
+    def run(self, *args):
+        """Run the kernel (loaded on first use) and count the call."""
         if self._kernel is None:
-            if self.backend != "pallas" and "jax" not in sys.modules:
-                return False
-            try:
-                import jax
-
-                kernel = self._loader()
-                if (
-                    self._device_only
-                    and self.backend != "pallas"
-                    and jax.default_backend() == "cpu"
-                ):
-                    self._kernel = False
-                else:
-                    self._kernel = kernel
-            except Exception:
-                self._kernel = False
-        return self._kernel
-
-    def disable(self) -> None:
-        """Rule the kernel out after a runtime failure (fallback stays)."""
-        self._kernel = False
+            self._kernel = self._loader()
+        with self._lock:
+            self.calls += 1
+        return self._kernel(*args)
 
 
 def _on_accelerator() -> bool:
@@ -120,12 +104,9 @@ def _on_accelerator() -> bool:
     accelerator — the ``auto`` rule every dispatcher shares."""
     if "jax" not in sys.modules:
         return False
-    try:
-        import jax
+    import jax
 
-        return jax.default_backend() != "cpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() != "cpu"
 
 
 # ---------------------------------------------------------------------------
@@ -133,12 +114,9 @@ def _on_accelerator() -> bool:
 
 
 def _on_tpu() -> bool:
-    try:
-        import jax
+    import jax
 
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _resolve(use_pallas: Optional[bool]):
@@ -157,17 +135,6 @@ def offsets_scan(lengths, use_pallas: Optional[bool] = None, **kw):
     from . import ref
 
     return ref.offsets_scan_ref(lengths)
-
-
-def delta_zigzag(x, use_pallas: Optional[bool] = None, **kw):
-    run, interp = _resolve(use_pallas)
-    if run:
-        from .delta_zigzag import delta_zigzag as k
-
-        return k(x, interpret=interp, **kw)
-    from . import ref
-
-    return ref.delta_zigzag_ref(x)
 
 
 def byteshuffle(planes, use_pallas: Optional[bool] = None, **kw):
@@ -207,10 +174,18 @@ def decode_offset_pages(planes, use_pallas: Optional[bool] = None, **kw):
 
 def flash_attention(q, k, v, causal=True, window=None, scale=None,
                     use_pallas: Optional[bool] = None, impl: str = "ref", **kw):
-    """impl: "ref" (naive softmax — the paper-faithful baseline shape) or
+    """Causal (optionally sliding-window) GQA attention.
+
+    The rule: the Pallas kernel (``kernels/flash_attention.py``) is
+    forward-only — it has no VJP — so it runs only when asked for with
+    ``use_pallas=True``.  Auto (``None``) compiles the XLA attention
+    selected by ``impl`` on every backend, TPU included, which is what
+    the model code and hence the differentiated train step use.
+
+    impl: "ref" (naive softmax — the paper-faithful baseline shape) or
     "chunked" (online-softmax scan over kv blocks — the §Perf variant)."""
-    run, interp = _resolve(use_pallas)
-    if run:
+    if use_pallas:
+        _, interp = _resolve(True)
         from .flash_attention import flash_attention as kern
 
         return kern(q, k, v, causal=causal, window=window,

@@ -27,15 +27,6 @@ def byteshuffle_ref(planes: jax.Array) -> jax.Array:
     return planes.T
 
 
-def delta_zigzag_ref(x: jax.Array) -> jax.Array:
-    """delta (vs previous element, first absolute) then zigzag, elementwise."""
-    d = jnp.concatenate([x[:1], x[1:] - x[:-1]])
-    bits = jnp.dtype(x.dtype).itemsize * 8 - 1
-    return ((d << 1) ^ (d >> bits)).astype(
-        jnp.uint32 if x.dtype == jnp.int32 else jnp.uint64
-    )
-
-
 # -- the decode chain (read-side inverses, DESIGN.md §9) --------------------
 
 

@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.configs import ARCHS, get_arch, smoke_config
 from repro.core import Collection, ColumnBatch, Leaf, ParallelWriter, Schema
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models.registry import build
 
@@ -56,6 +57,7 @@ def main(argv=None) -> None:
     ap.add_argument("--max-new", type=int, default=32)
     ap.add_argument("--out", default="/tmp/generations.rntj")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_arch(args.arch)
     bundle = build(cfg)

@@ -17,6 +17,7 @@ from pathlib import Path
 import jax
 
 from repro.configs import ARCHS, get_arch, smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.models.registry import build
 from repro.pipeline import PackedLoader, ingest_corpus, synth_corpus
@@ -40,6 +41,7 @@ def main(argv=None) -> None:
     ap.add_argument("--production-mesh", action="store_true",
                     help="use the 16x16 production mesh (needs 256 devices)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_arch(args.arch)
     bundle = build(cfg)

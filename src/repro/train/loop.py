@@ -79,7 +79,9 @@ class TrainLoop:
             microbatches=self.config.microbatches,
         )
         self._jitted_for = jitted_for
+        self._in_shardings_for = shardings["in_shardings_for"]
         self._step_fn = None
+        self._batch_sh = None
         self.step = 0
 
         latest = self.mgr.latest_step()
@@ -131,17 +133,23 @@ class TrainLoop:
 
     # -- run ----------------------------------------------------------------
 
+    def place(self, batch: Dict) -> Dict:
+        """Put a loader batch (host or device arrays) on the step's batch
+        shardings: the device engine decodes onto one device, and a mesh
+        of several takes its shards from there."""
+        if self._step_fn is None:
+            shapes = jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), batch)
+            self._step_fn = self._jitted_for(shapes)
+            self._batch_sh = self._in_shardings_for(shapes)[3]
+        return jax.device_put(batch, self._batch_sh)
+
     def run(self, steps: Optional[int] = None) -> List[StepEvent]:
         steps = steps if steps is not None else self.config.steps
         batches = self.loader.batches()
         target = self.step + steps
         while self.step < target:
-            batch = next(batches)
-            jb = {k: jax.numpy.asarray(v) for k, v in batch.items()}
-            if self._step_fn is None:
-                shapes = jax.tree_util.tree_map(
-                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), jb)
-                self._step_fn = self._jitted_for(shapes)
+            jb = self.place(next(batches))
             t0 = time.perf_counter()
             self.params, self.opt_state, self.err_state, metrics = self._step_fn(
                 self.params, self.opt_state, self.err_state, jb)

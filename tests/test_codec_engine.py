@@ -536,17 +536,17 @@ def test_forced_pallas_byteshuffle_matches_numpy(monkeypatch):
     """REPRO_SHUFFLE_BACKEND=pallas must be bit-identical to the numpy
     split (runs the kernel in interpret mode on CPU backends)."""
     pytest.importorskip("jax")
-    monkeypatch.setattr(E._SHUFFLE, "backend", "pallas")
-    monkeypatch.setattr(E._SHUFFLE, "_kernel", None)  # re-resolve
+    monkeypatch.setattr(E.BYTESHUFFLE, "backend", "pallas")
+    before = E.BYTESHUFFLE.calls
     rng = np.random.default_rng(14)
     for dtype, per in [(np.float32, 64), (np.int64, 100), (np.float64, 33)]:
         arr = rng.uniform(0, 100, 257).astype(dtype)
         got = bytes(E.precondition_column_pages(arr, "split", per))
-        monkeypatch.setattr(E._SHUFFLE, "backend", "numpy")
+        monkeypatch.setattr(E.BYTESHUFFLE, "backend", "numpy")
         want = bytes(E.precondition_column_pages(arr, "split", per))
-        monkeypatch.setattr(E._SHUFFLE, "backend", "pallas")
+        monkeypatch.setattr(E.BYTESHUFFLE, "backend", "pallas")
         assert got == want, f"pallas byteshuffle differs for {dtype}"
-    assert E._SHUFFLE._kernel not in (None, False)  # the kernel actually ran
+    assert E.BYTESHUFFLE.calls == before + 3  # the kernel actually ran
 
 
 def test_shuffle_auto_backend_stays_numpy_on_cpu():

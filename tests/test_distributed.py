@@ -32,7 +32,8 @@ def run_with_devices(n: int, body: str) -> str:
         res = subprocess.run([sys.executable, "-c", prog], capture_output=True,
                              text=True, timeout=budget,
                              env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                                  "HOME": "/root"})
+                                  "HOME": os.path.expanduser("~"),
+                                  "JAX_PLATFORMS": "cpu"})
     except subprocess.TimeoutExpired:
         pytest.skip(f"{n}-device subprocess exceeded {budget}s on this machine")
     assert res.returncode == 0, res.stderr[-3000:]
@@ -104,7 +105,7 @@ def test_elastic_replan():
 def test_hierarchical_psum_equals_flat_psum():
     out = run_with_devices(8, """
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.distributed.collectives import hierarchical_psum
         mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))

@@ -1,0 +1,118 @@
+"""Ahead-of-time compiles of the main path's kernels for a described v5e.
+
+Nothing here runs on a chip: the TPU compiler, which ships with jax,
+compiles for a topology that is described, not attached, and refuses what
+the chip would refuse (unaligned blocks, primitives Mosaic cannot lower)
+where interpret mode accepts it.  Shapes are the real widths of the main
+path.  The topology is described inside a module-scoped fixture, never at
+import: only one process at a time may load the TPU library.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_arch
+from repro.kernels import ops
+from repro.kernels.byteshuffle import byteshuffle_pages
+from repro.kernels.decode_pages import (
+    decode_offset_pages, device_decode_offsets, unsplit_pages,
+)
+from repro.kernels.offsets_scan import offsets_scan
+
+KERNEL = "tpu_custom_call"
+PAGE = 64 * 1024  # the default page size in bytes
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_cache():
+    """A compile for a described chip cannot be read back from the
+    persistent cache: keep it out of the cache."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _hlo(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def test_offsets_scan_compiles(one_chip, no_cache):
+    sizes = jax.ShapeDtypeStruct((200_000,), jnp.int32, sharding=one_chip)
+    assert KERNEL in _hlo(offsets_scan, sizes)
+
+
+def test_byteshuffle_pages_compiles(one_chip, no_cache):
+    per = PAGE // 4  # 16 384 int32 elements per page
+    pages = jax.ShapeDtypeStruct((8, per, 4), jnp.uint8, sharding=one_chip)
+    assert KERNEL in _hlo(byteshuffle_pages, pages)
+
+
+def test_unsplit_pages_compiles(one_chip, no_cache):
+    per = PAGE // 4
+    planes = jax.ShapeDtypeStruct((8, 4, per), jnp.uint8, sharding=one_chip)
+    assert KERNEL in _hlo(unsplit_pages, planes)
+
+
+@pytest.mark.parametrize("per", [PAGE // 8, 2048, 1000])
+def test_decode_offset_pages_compiles(one_chip, no_cache, per):
+    planes = jax.ShapeDtypeStruct((16, 8, per), jnp.uint8, sharding=one_chip)
+    assert KERNEL in _hlo(decode_offset_pages, planes)
+
+
+def test_device_decode_offsets_compiles(one_chip, no_cache):
+    per = PAGE // 8
+    n = 5 * per + 77  # full pages through the kernel plus a partial tail
+    raw = jax.ShapeDtypeStruct((n * 8,), jnp.uint8, sharding=one_chip)
+    fn = functools.partial(device_decode_offsets, n=n, per=per,
+                           use_pallas=True, interpret=False)
+    assert KERNEL in _hlo(fn, raw)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "gradient"])
+def test_train_step_attention_compiles(one_chip, no_cache, grad):
+    """smollm-360m attention as the train step calls it: XLA attention by
+    the rule in ``ops.flash_attention`` (the Pallas kernel has no VJP),
+    forward and gradient, at batch 4 x 2048."""
+    cfg = get_arch("smollm-360m")
+    b, s, d = 4, 2048, cfg.resolved_head_dim
+    q = jax.ShapeDtypeStruct((b, cfg.n_heads, s, d), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, cfg.n_kv_heads, s, d), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def attn(q, k, v):
+        return ops.flash_attention(q, k, v, causal=True, window=cfg.window,
+                                   impl=cfg.attn_impl)
+
+    def loss(q, k, v):
+        return jnp.sum(attn(q, k, v).astype(jnp.float32))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else attn
+    hlo = _hlo(fn, q, kv, kv)
+    assert KERNEL not in hlo  # XLA attention: no Mosaic kernel in the step
+    assert "dot" in hlo or "convolution" in hlo

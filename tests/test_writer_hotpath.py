@@ -382,8 +382,7 @@ def test_integrate_sizes_forced_pallas_matches_numpy(monkeypatch):
     """REPRO_OFFSETS_BACKEND=pallas must be bit-identical to numpy (runs
     the kernel in interpret mode on CPU backends)."""
     jax = pytest.importorskip("jax")
-    monkeypatch.setattr(E._OFFSETS, "backend", "pallas")
-    monkeypatch.setattr(E._OFFSETS, "_kernel", None)  # re-resolve under the override
+    monkeypatch.setattr(E.OFFSETS_SCAN, "backend", "pallas")
     rng = np.random.default_rng(1)
     sizes = rng.poisson(7, 300).astype(np.int64)
     got = E.integrate_sizes(sizes, base=5)
