@@ -44,6 +44,7 @@ from . import compression as comp
 from .colbuf import ColumnBuffer
 from .encoding import EncodeScratch, integrate_sizes, precondition_column_pages
 from .pages import PageDesc, build_page, elements_per_page
+from .stats import span
 from .schema import (
     ENC_NONE,
     KIND_OFFSET,
@@ -336,7 +337,7 @@ class ClusterBuilder:
             zm[col.index] = entry
         return zm
 
-    def seal(self, pool=None) -> SealedCluster:
+    def seal(self, pool=None, key=None) -> SealedCluster:
         """Serialize + compress all pages.  No lock required (paper §4.1).
 
         The single compression code path behind both ROOT-style IMT in the
@@ -352,8 +353,16 @@ class ClusterBuilder:
         — mid-seal, once the trial results are recorded — to either the
         codec or raw storage, so a doomed codec never burns more than the
         sample on its first cluster.
+
+        Runs as the ``writer.seal`` span (``key``: the producer and its
+        cluster number), whose clock gives ``seal_ns``.
         """
-        t0 = _ns()
+        with span("writer.seal", key, timed=True) as sp:
+            sealed = self._seal(pool)
+        sealed.seal_ns = sp.ns
+        return sealed
+
+    def _seal(self, pool) -> SealedCluster:
         # zone maps come first: the column views are still contiguous and
         # untouched (gathering may detach raw-aliased buffers later)
         zonemaps = self._zone_maps()
@@ -418,7 +427,6 @@ class ClusterBuilder:
             n_elements=n_elements,
             pages=descs,
             uncompressed_bytes=self.uncompressed_bytes,
-            seal_ns=_ns() - t0,
             compress_ns=compress_ns,
             codec_stats=codec_stats,
             iovecs=iovecs,

@@ -885,10 +885,9 @@ class IOEngine:
         group = _ExtentGroup(len(stripes), nbytes, owner, len(stripes) > 1)
         with self._cv:
             self._pending += len(stripes)
-            depth = self._pending
         if self.stats is not None:
             for _ in stripes:
-                self.stats.note_io_job(depth, self._inflight)
+                self.stats.note_io_job(self._inflight)
         if self._ring is not None:
             for s_off, s_parts, s_n in stripes:
                 self._ring.submit(group, s_off, s_parts, s_n)

@@ -62,6 +62,7 @@ from .metadata import (
 )
 from .recover import _read_exact, _verify_cluster_pages
 from .schema import Schema
+from .stats import span
 from .writer import ParallelWriter, WriteOptions
 
 _POLL_S = 0.02  # rendezvous poll period
@@ -218,7 +219,8 @@ class ParticipantWriter(ParallelWriter):
         # outlasts the fencing grace (~2x lease_interval) must stay
         # leased, or the coordinator fences a healthy writer mid-close
         # and spuriously degrades the seal.
-        self._io.fsync()
+        with span("writer.fsync"):
+            self._io.fsync()
         self._stop_heartbeat()
         if self._commit_error is None:
             self._mp_session.done()

@@ -67,7 +67,7 @@ from .metadata import (
 )
 from .pages import PageDesc, _thread_scratch, decode_page_into
 from .schema import KIND_OFFSET, ColumnSpec, Schema, recompose_entries
-from .stats import ReaderStats, _merge_codec_stats
+from .stats import ReaderStats, _merge_codec_stats, span
 
 _ns = time.perf_counter_ns
 
@@ -1156,7 +1156,14 @@ class RNTJReader:
         buffer, so ``dev`` may alias ``staging`` — recycling it here
         would let the next cluster's fill clobber this cluster's device
         bytes mid-decode.
+
+        Runs as the ``reader.stage`` span (key: the cluster index).
         """
+        with span("reader.stage", cluster_index):
+            return self._stage_host(cluster_index, columns)
+
+    def _stage_host(self, cluster_index: int,
+                    columns: Optional[Sequence[int]]):
         import jax
 
         targets = (list(columns) if columns is not None
@@ -1340,15 +1347,18 @@ class RNTJReader:
     def _finish_staged(self, staged, cluster_index: int) -> Dict[int, object]:
         """Device half + host fallbacks for one staged cluster, then
         recycle the staging buffer (safe only now — ``dev`` may alias
-        it, see :meth:`_stage_cluster_device`)."""
+        it, see :meth:`_stage_cluster_device`): the ``reader.decode``
+        span."""
         plans, dev, fallback, staging = staged
-        out = self._decode_staged(plans, dev) if plans else {}
-        if staging is not None and self._bufpool is not None:
-            # the decode outputs are materialized (block_until_ready in
-            # _decode_staged), so nothing references the staged bytes
-            self._bufpool.put(staging)
-        if fallback:
-            out.update(self.read_cluster(cluster_index, fallback))
+        with span("reader.decode", cluster_index):
+            out = self._decode_staged(plans, dev) if plans else {}
+            if staging is not None and self._bufpool is not None:
+                # the decode outputs are materialized (block_until_ready
+                # in _decode_staged), so nothing references the staged
+                # bytes
+                self._bufpool.put(staging)
+            if fallback:
+                out.update(self.read_cluster(cluster_index, fallback))
         return out
 
     def iter_clusters_device(
@@ -1390,9 +1400,9 @@ class RNTJReader:
                     )
                     nxt += 1
                 i, fut = pending.popleft()
-                t0 = _ns()
-                staged = fut.result()
-                self.stats.add_wait_ns(_ns() - t0)
+                with span("reader.wait", i, timed=True) as sp:
+                    staged = fut.result()
+                self.stats.add_wait_ns(sp.ns)
                 # top up BEFORE the device half + yield: the next
                 # cluster's host half makes progress while this one
                 # decodes on device and the consumer packs it

@@ -15,14 +15,24 @@ metadata + write path) and ``io`` (time inside ``pwrite``) — so benchmarks
 can attribute wins to the right layer.  All mutation goes through locked
 ``add_*``/``merge_*`` methods: with pipelined sealing, commits run on
 background threads concurrently with producer fills.
+
+:func:`span` marks one layer's unit of work (a train step, a loader
+batch, a cluster staged or sealed) on the JAX profiler's clock, beside
+the device ops; while a profiler session runs, :func:`records` keeps them
+for the benchmark's per-layer metrics.  Where a span and a counter time
+the same interval, the counter is read from the span's clock.
 """
 
 from __future__ import annotations
 
+import itertools
+import sys
 import threading
 import time
+import warnings
+from collections import deque
 from dataclasses import dataclass, field, replace
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional
 
 
 def _merge_codec_stats(into: Dict[int, List[int]],
@@ -166,7 +176,6 @@ class WriterStats:
     # -- I/O engine (write-behind / striping, DESIGN.md §6) -----------------
     io_stall_ns: int = 0     # producer time blocked on the in-flight budget
     io_jobs: int = 0         # write jobs executed by the engine
-    io_queue_peak: int = 0   # max write jobs queued/running at once
     io_inflight_peak: int = 0  # max write-behind bytes in flight at once
     # -- async submission + buffer pool (DESIGN.md §6.7/§6.8) ---------------
     io_submit_ns: int = 0    # producer time spent submitting queued extents
@@ -260,13 +269,11 @@ class WriterStats:
         with self._mu:
             self.io_ring_fallbacks += 1
 
-    def note_io_job(self, queued: int, inflight: int) -> None:
-        """One engine write job observed with ``queued`` jobs outstanding
-        and ``inflight`` write-behind bytes admitted."""
+    def note_io_job(self, inflight: int) -> None:
+        """One engine write job observed with ``inflight`` write-behind
+        bytes admitted."""
         with self._mu:
             self.io_jobs += 1
-            if queued > self.io_queue_peak:
-                self.io_queue_peak = queued
             if inflight > self.io_inflight_peak:
                 self.io_inflight_peak = inflight
 
@@ -309,7 +316,6 @@ class WriterStats:
             "io_stall_ms": self.io_stall_ns / 1e6,
             "io_submit_ms": self.io_submit_ns / 1e6,
             "io_jobs": self.io_jobs,
-            "io_queue_peak": self.io_queue_peak,
             "io_inflight_peak_bytes": self.io_inflight_peak,
             "pool_hits": self.pool_hits,
             "pool_misses": self.pool_misses,
@@ -488,3 +494,216 @@ class ReaderStats:
             "io_hedges": self.io.hedges,
             "io_hedge_wins": self.io.hedge_wins,
         }
+
+
+# ---------------------------------------------------------------------------
+# Program spans on the profiler's clock
+
+_ns = time.perf_counter_ns
+
+#: spans kept in memory at most (the newest); older ones are dropped
+SPAN_LOG_CAPACITY = 1 << 18
+
+#: JAX's compile events recorded as spans: one ``compile`` per program
+#: lowered (the key is the program)
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile",
+}
+
+
+class SpanRecord(NamedTuple):
+    """One finished span: ``parent`` is the ``id`` of the span open on the
+    same thread when it began (None at the top), times are
+    ``perf_counter_ns``."""
+
+    id: int
+    name: str
+    key: Any
+    start_ns: int
+    end_ns: int
+    parent: Optional[int]
+    thread: int
+
+    @property
+    def ns(self) -> int:
+        return self.end_ns - self.start_ns
+
+
+class _SpanLog:
+    """The newest ``capacity`` records and a count of those dropped."""
+
+    def __init__(self, capacity: int) -> None:
+        self._mu = threading.Lock()
+        self._items: deque = deque(maxlen=capacity)
+        self.dropped = 0
+
+    def add(self, rec: SpanRecord) -> None:
+        with self._mu:
+            if len(self._items) == self._items.maxlen:
+                self.dropped += 1
+            self._items.append(rec)
+
+    def records(self) -> List[SpanRecord]:
+        with self._mu:
+            return list(self._items)
+
+    def clear(self) -> None:
+        with self._mu:
+            self._items.clear()
+            self.dropped = 0
+
+
+# process-wide, like the profiler session it mirrors
+_log = _SpanLog(SPAN_LOG_CAPACITY)
+_ids = itertools.count(1)
+_local = threading.local()
+_bind_mu = threading.Lock()
+_is_enabled = None      # the profiler's session check, bound with jax
+_annotation = None      # jax.profiler.TraceAnnotation
+_step_annotation = None  # jax.profiler.StepTraceAnnotation
+
+
+def _never() -> bool:
+    return False
+
+
+def _bind_jax() -> bool:
+    """Bind the profiler's hooks once jax is imported (a process that
+    never imports jax has no profiler session to check).  A jax without
+    the session check reads as no session, with one warning: spans must
+    never stop the data path."""
+    global _is_enabled, _annotation, _step_annotation
+    if "jax" not in sys.modules:
+        return False
+    with _bind_mu:
+        if _is_enabled is None:
+            try:
+                import jax
+                from jax._src.lib import _profiler
+
+                is_enabled = _profiler.TraceMe.is_enabled   # jax 0.9.0
+                _annotation = jax.profiler.TraceAnnotation
+                _step_annotation = jax.profiler.StepTraceAnnotation
+                jax.monitoring.register_event_duration_secs_listener(
+                    _on_compile)
+            except (ImportError, AttributeError) as exc:
+                warnings.warn(f"program spans are off: this jax has no "
+                              f"profiler session check ({exc})",
+                              RuntimeWarning, stacklevel=2)
+                is_enabled = _never
+            _is_enabled = is_enabled
+    return True
+
+
+def profiler_active() -> bool:
+    """True while a JAX profiler session collects host events."""
+    if _is_enabled is None and not _bind_jax():
+        return False
+    return _is_enabled()
+
+
+def _stack() -> List[int]:
+    st = getattr(_local, "stack", None)
+    if st is None:
+        st = _local.stack = []
+    return st
+
+
+class _Clock:
+    """A span's two clock reads (``timed`` spans read them always)."""
+
+    __slots__ = ("t0", "t1")
+
+    def __enter__(self):
+        self.t0 = _ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = _ns()
+        return False
+
+    @property
+    def ns(self) -> int:
+        return self.t1 - self.t0
+
+
+class _Span(_Clock):
+    __slots__ = ("name", "key", "step", "id", "parent", "ann", "stack")
+
+    def __init__(self, name: str, key, step: bool) -> None:
+        self.name, self.key, self.step = name, key, step
+
+    def __enter__(self):
+        label = "rntj." + self.name
+        self.ann = (_step_annotation(label, step_num=self.key) if self.step
+                    else _annotation(label))
+        self.ann.__enter__()
+        self.stack = _stack()
+        self.parent = self.stack[-1] if self.stack else None
+        self.id = next(_ids)
+        self.stack.append(self.id)
+        self.t0 = _ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = _ns()
+        self.stack.remove(self.id)
+        self.ann.__exit__(*exc)
+        _log.add(SpanRecord(self.id, self.name, self.key, self.t0, self.t1,
+                            self.parent, threading.get_ident()))
+        return False
+
+
+class _NullSpan:
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NULL = _NullSpan()
+
+
+def span(name: str, key=None, *, timed: bool = False, step: bool = False):
+    """Context manager marking ``rntj.<name>`` in the profiler's trace and
+    keeping its :class:`SpanRecord` while a session is active; with none
+    active it costs one check.  ``key`` names the unit of work (spans of
+    one unit share it); ``step=True`` writes a ``StepTraceAnnotation``
+    whose step number is ``key``; ``timed=True`` returns an object whose
+    ``t0``/``t1``/``ns`` hold the span's clock reads, session or not, for
+    a counter that times the same interval."""
+    if profiler_active():
+        return _Span(name, key, step)
+    return _Clock() if timed else _NULL
+
+
+def _on_compile(event: str, duration: float, fun_name=None, **_kw) -> None:
+    """``jax.monitoring`` listener: a compile as a span that ends now, under
+    the span open on the compiling thread.  Kept in memory only: the
+    profiler's trace holds JAX's own compile events."""
+    name = _COMPILE_EVENTS.get(event)
+    if name is None or not _is_enabled():
+        return
+    t1 = _ns()
+    st = _stack()
+    _log.add(SpanRecord(next(_ids), name, fun_name, t1 - int(duration * 1e9),
+                        t1, st[-1] if st else None, threading.get_ident()))
+
+
+def records() -> List[SpanRecord]:
+    """The spans kept while a profiler session was active, oldest first
+    (a span is kept when it ends, so children precede their parent)."""
+    return _log.records()
+
+
+def dropped() -> int:
+    """Records dropped to keep :func:`records` bounded."""
+    return _log.dropped
+
+
+def clear() -> None:
+    """Forget every kept record and the dropped count."""
+    _log.clear()

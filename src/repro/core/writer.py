@@ -47,7 +47,6 @@ from __future__ import annotations
 
 import os
 import threading
-import time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence
@@ -77,9 +76,7 @@ from .metadata import (
 )
 from .pages import DEFAULT_PAGE_SIZE, PageDesc
 from .schema import ColumnBatch, Schema
-from .stats import CountingLock, WriterStats
-
-_ns = time.perf_counter_ns
+from .stats import CountingLock, WriterStats, span
 
 
 @dataclass
@@ -345,17 +342,24 @@ class _WriterBase:
             epoch=self._jrec_epoch,
         )
 
-    def _commit_cluster(self, sealed: SealedCluster) -> None:
+    def _commit_cluster(self, sealed: SealedCluster, key=None) -> None:
         """The paper's critical section (§4.2/§4.3), buffered mode.
 
         With write-behind (``io_inflight_bytes > 0``) the backpressure
         gate runs BEFORE the critical section — a producer stalled on
         storage never blocks the other producers' commits — and the
         critical section only enqueues the extent; the engine's workers
-        drain it while this producer seals ahead.
+        drain it while this producer seals ahead.  Runs as the
+        ``writer.commit`` span, whose clock gives ``commit_ns``.
         """
+        with span("writer.commit", key, timed=True) as sp:
+            io_ns = self._commit_extent(sealed)
+        self.stats.add_sealed_cluster(sealed, commit_ns=sp.ns, io_ns=io_ns)
+
+    def _commit_extent(self, sealed: SealedCluster) -> int:
+        """Reserve, publish and write one sealed cluster; returns the io
+        time spent on this thread."""
         opts = self.options
-        t0 = _ns()
         # With the journal on, the reserved extent is
         # [envelope][payload][journal record], submitted as ONE vectored
         # engine write — no extra syscall.  The page list's byte_offset
@@ -408,7 +412,7 @@ class _WriterBase:
             # actual bytes go out truly in parallel (paper §5).
             io_ns = self._submit_or_latch(ext, parts, total, owner=sealed)
             self._post_commit(ext)
-        self.stats.add_sealed_cluster(sealed, commit_ns=_ns() - t0, io_ns=io_ns)
+        return io_ns
 
     def _poison(self, e: BaseException) -> None:
         """First seal/commit failure latches here; close() then refuses to
@@ -436,8 +440,9 @@ class _WriterBase:
             raise
 
     def _commit_page(self, payload, desc: PageDesc,
-                     build_ns: int = 0) -> PageDesc:
-        """Page-granular critical section (unbuffered mode).
+                     build_ns: int = 0, key=None) -> PageDesc:
+        """Page-granular critical section (unbuffered mode), as the
+        ``writer.commit`` span.
 
         A pooled raw payload (a memoryview of a BufferPool array, see
         ``build_page``) rides with a ``Recyclable`` owner so the engine
@@ -450,14 +455,14 @@ class _WriterBase:
             and isinstance(payload.obj, np.ndarray)
         ):
             owner = Recyclable([payload.obj])
-        t0 = _ns()
-        self._io.admit(len(payload))
-        with self.lock:
-            off = self.sink.reserve(len(payload))
-            io_ns = self._submit_or_latch(off, [payload], len(payload),
-                                          owner=owner)
+        with span("writer.commit", key, timed=True) as sp:
+            self._io.admit(len(payload))
+            with self.lock:
+                off = self.sink.reserve(len(payload))
+                io_ns = self._submit_or_latch(off, [payload], len(payload),
+                                              owner=owner)
         desc.offset = off
-        self.stats.add_page(len(payload), commit_ns=_ns() - t0, io_ns=io_ns,
+        self.stats.add_page(len(payload), commit_ns=sp.ns, io_ns=io_ns,
                             codec=desc.codec,
                             uncompressed_size=desc.uncompressed_size,
                             build_ns=build_ns)
@@ -556,48 +561,51 @@ class _WriterBase:
     # -- finalization ---------------------------------------------------------
 
     def _finalize(self) -> None:
-        """Seal the container: page list + footer + anchor + final fsync.
+        """Seal the container: page list + footer + anchor (the
+        ``writer.finalize`` span) + final fsync (``writer.fsync``).
         Runs only on a clean close (engine drained, nothing poisoned).
         Multi-writer participants override this — the coordinator owns the
         footer; a participant just makes its clusters durable and reports
         DONE to the shared log."""
-        if (self._journal and self._clusters
-                and self._io._fsync_interval
-                and not self._io._fsync_every):
-            # journal-before-footer barrier (DESIGN.md §8.3):
-            # every committed cluster's envelope + journal record
-            # is durable before the first finalization byte
-            # exists, so a crash during finalization always
-            # leaves a journal that covers all committed data.
-            # Only the byte-interval policy needs it: every-cluster
-            # already synced each extent, and under on_close
-            # nothing is durable until the single close fsync
-            # below — which then covers journal and footer alike.
-            self._io.fsync()
-        with self.lock:
-            extra = None
-            sc = build_member_sidecar(self._clusters)
-            if sc is not None:
-                sc_off = self.sink.reserve(len(sc))
-                self._meta_pwrite(sc_off, sc)
-                extra = {"members": [sc_off, len(sc)]}
-            zm = encode_zonemaps(self._zonemaps)
-            if zm is not None:
-                extra = dict(extra or {})
-                extra["zonemaps"] = zm
-            pl = build_pagelist(self._clusters, self.schema.n_columns)
-            pl_off = self.sink.reserve(len(pl))
-            self._meta_pwrite(pl_off, pl)
-            ftr = build_footer(self._n_entries, len(self._clusters),
-                               (pl_off, len(pl)), extra=extra)
-            f_off = self.sink.reserve(len(ftr))
-            self._meta_pwrite(f_off, ftr)
-            anchor = build_anchor(
-                self._header_loc, (f_off, len(ftr)), self._n_entries,
-                len(self._clusters),
-            )
-            a_off = self.sink.reserve(ANCHOR_SIZE)
-            self._meta_pwrite(a_off, anchor)
+        with span("writer.finalize"):
+            if (self._journal and self._clusters
+                    and self._io._fsync_interval
+                    and not self._io._fsync_every):
+                # journal-before-footer barrier (DESIGN.md §8.3):
+                # every committed cluster's envelope + journal record
+                # is durable before the first finalization byte
+                # exists, so a crash during finalization always
+                # leaves a journal that covers all committed data.
+                # Only the byte-interval policy needs it: every-cluster
+                # already synced each extent, and under on_close
+                # nothing is durable until the single close fsync
+                # below — which then covers journal and footer alike.
+                with span("writer.fsync"):
+                    self._io.fsync()
+            with self.lock:
+                extra = None
+                sc = build_member_sidecar(self._clusters)
+                if sc is not None:
+                    sc_off = self.sink.reserve(len(sc))
+                    self._meta_pwrite(sc_off, sc)
+                    extra = {"members": [sc_off, len(sc)]}
+                zm = encode_zonemaps(self._zonemaps)
+                if zm is not None:
+                    extra = dict(extra or {})
+                    extra["zonemaps"] = zm
+                pl = build_pagelist(self._clusters, self.schema.n_columns)
+                pl_off = self.sink.reserve(len(pl))
+                self._meta_pwrite(pl_off, pl)
+                ftr = build_footer(self._n_entries, len(self._clusters),
+                                   (pl_off, len(pl)), extra=extra)
+                f_off = self.sink.reserve(len(ftr))
+                self._meta_pwrite(f_off, ftr)
+                anchor = build_anchor(
+                    self._header_loc, (f_off, len(ftr)), self._n_entries,
+                    len(self._clusters),
+                )
+                a_off = self.sink.reserve(ANCHOR_SIZE)
+                self._meta_pwrite(a_off, anchor)
         # Durability before close: fsync the sink unconditionally
         # (sinks without a backing fd make it a no-op counter
         # bump).  The seed gated this on readable() — which
@@ -606,11 +614,29 @@ class _WriterBase:
         # engine so it is retried and a final failure poisons
         # (and is accounted) like any other I/O error.  The fsync
         # must precede the io-stats snapshot to be counted.
-        self._io.fsync()
+        with span("writer.fsync"):
+            self._io.fsync()
+
+    def _flush_producers(self) -> None:
+        """Flush what the producers still hold (the subclasses' open
+        builders) before the drain; a failure poisons finalization."""
 
     def close(self) -> None:
+        """Flush, drain, finalize and release: the ``writer.close`` span,
+        with ``writer.drain``, ``writer.finalize`` and ``writer.fsync``
+        inside it."""
         if self._closed:
             return
+        with span("writer.close"):
+            self._close()
+        if self._commit_error is not None:
+            raise RuntimeError(
+                "writer aborted: a cluster failed to seal or commit; the "
+                "file was NOT finalized (no footer written)"
+            ) from self._commit_error
+
+    def _close(self) -> None:
+        self._flush_producers()
         self._closed = True
         try:
             if self._pool is not None:
@@ -618,7 +644,8 @@ class _WriterBase:
             # drain-before-footer: every queued write-behind extent must
             # land (or fail, poisoning _commit_error via the engine's
             # error hook) before any finalization byte is even built
-            self._io.drain()
+            with span("writer.drain"):
+                self._io.drain()
             if self._commit_error is None:
                 self._finalize()
         finally:
@@ -638,11 +665,6 @@ class _WriterBase:
                     self.sink.close()
                 finally:
                     self.stats.merge_io(self.sink.io.snapshot())
-        if self._commit_error is not None:
-            raise RuntimeError(
-                "writer aborted: a cluster failed to seal or commit; the "
-                "file was NOT finalized (no footer written)"
-            ) from self._commit_error
 
     def __enter__(self):
         return self
@@ -678,10 +700,10 @@ class _PipelinedSealer:
         self._future = None
         self._spare: Optional[ClusterBuilder] = None
 
-    def _run(self, builder: ClusterBuilder) -> ClusterBuilder:
+    def _run(self, builder: ClusterBuilder, key) -> ClusterBuilder:
         try:
-            sealed = builder.seal(self._writer._pool)
-            self._writer._commit_cluster(sealed)
+            sealed = builder.seal(self._writer._pool, key)
+            self._writer._commit_cluster(sealed, key)
         except BaseException as e:
             # the cluster's data is lost (its builder was handed off):
             # poison finalization directly, so even a caller that
@@ -691,11 +713,11 @@ class _PipelinedSealer:
             raise
         return builder  # drained: its buffers are reusable now
 
-    def submit(self, builder: ClusterBuilder) -> ClusterBuilder:
+    def submit(self, builder: ClusterBuilder, key=None) -> ClusterBuilder:
         self.wait()
         nxt = self._spare if self._spare is not None else self._writer._make_builder()
         self._spare = None
-        self._future = self._exec.submit(self._run, builder)
+        self._future = self._exec.submit(self._run, builder, key)
         return nxt
 
     def wait(self) -> None:
@@ -731,18 +753,20 @@ class SequentialWriter(_WriterBase):
             if self.options.pipelined_seal and self.options.buffered
             else None
         )
-        self._fill_ns = 0
+        self._n_fills = 0
+        self._n_sealed = 0
 
     def fill(self, entry: Dict) -> None:
-        t0 = _ns()
-        self._builder.fill(entry)
-        self._fill_ns += _ns() - t0
-        self._maybe_flush()
+        self._fill(self._builder.fill, entry)
 
     def fill_batch(self, batch: ColumnBatch) -> None:
-        t0 = _ns()
-        self._builder.fill_batch(batch)
-        self._fill_ns += _ns() - t0
+        self._fill(self._builder.fill_batch, batch)
+
+    def _fill(self, append, data) -> None:
+        with span("writer.fill", (0, self._n_fills), timed=True) as sp:
+            append(data)
+        self._n_fills += 1
+        self.stats.add_fill_ns(sp.ns)
         self._maybe_flush()
 
     def _maybe_flush(self) -> None:
@@ -754,26 +778,22 @@ class SequentialWriter(_WriterBase):
             if self._sealer is not None:
                 self._sealer.wait()
             return
+        key = (0, self._n_sealed)
+        self._n_sealed += 1
         if self._sealer is not None:
-            self._builder = self._sealer.submit(self._builder)
+            self._builder = self._sealer.submit(self._builder, key)
         else:
-            self._commit_cluster(self._builder.seal(self._pool))
+            self._commit_cluster(self._builder.seal(self._pool, key), key)
 
-    def close(self) -> None:
-        if not self._closed:
-            try:
-                self.flush_cluster()
-                if self._sealer is not None:
-                    self._sealer.close()
-            except BaseException as e:
-                # a cluster was lost: poison finalization, surface via
-                # super().close() after resources are released
-                if self._commit_error is None:
-                    self._commit_error = e
-            finally:
-                self.stats.add_fill_ns(self._fill_ns)
-                self._fill_ns = 0
-        super().close()
+    def _flush_producers(self) -> None:
+        try:
+            self.flush_cluster()
+            if self._sealer is not None:
+                self._sealer.close()
+        except BaseException as e:
+            # a cluster was lost: poison finalization, surface from
+            # close() after resources are released
+            self._poison(e)
 
 
 # ---------------------------------------------------------------------------
@@ -789,28 +809,39 @@ class FillContext:
     background thread while this producer fills the next builder.
     """
 
-    def __init__(self, writer: "ParallelWriter"):
+    def __init__(self, writer: "ParallelWriter", producer: int = 0):
         self.writer = writer
+        self.producer = producer
         o = writer.options
         self.builder = writer._make_builder()
         self._page_buf: List = []  # unbuffered mode: descs of committed pages
         self._sealer = (
             _PipelinedSealer(writer) if o.pipelined_seal and o.buffered else None
         )
-        self._fill_ns = 0
+        self._n_fills = 0
+        self._n_sealed = 0
         self._ctx_closed = False
 
     def fill(self, entry: Dict) -> None:
-        t0 = _ns()
-        self.builder.fill(entry)
-        self._fill_ns += _ns() - t0
-        self._maybe_flush()
+        self._fill(self.builder.fill, entry)
 
     def fill_batch(self, batch: ColumnBatch) -> None:
-        t0 = _ns()
-        self.builder.fill_batch(batch)
-        self._fill_ns += _ns() - t0
+        self._fill(self.builder.fill_batch, batch)
+
+    def _fill(self, append, data) -> None:
+        """The builder append alone, as the ``writer.fill`` span (key: the
+        producer and its fill number), whose clock feeds ``fill_ns``."""
+        with span("writer.fill", (self.producer, self._n_fills),
+                  timed=True) as sp:
+            append(data)
+        self._n_fills += 1
+        self.writer.stats.add_fill_ns(sp.ns)
         self._maybe_flush()
+
+    def _cluster_key(self):
+        key = (self.producer, self._n_sealed)
+        self._n_sealed += 1
+        return key
 
     def _maybe_flush(self) -> None:
         o = self.writer.options
@@ -818,7 +849,8 @@ class FillContext:
             # the writer pool parallelizes chunk-framed page members; the
             # drain itself runs on this producer thread
             for payload, desc, ns in self.builder.drain_full_pages(self.writer._pool):
-                self._page_buf.append(self.writer._commit_page(payload, desc, ns))
+                self._page_buf.append(self.writer._commit_page(
+                    payload, desc, ns, (self.producer, self._n_sealed)))
         if self.builder.uncompressed_bytes >= o.cluster_bytes:
             self.flush_cluster()
 
@@ -827,14 +859,17 @@ class FillContext:
             if self._sealer is not None:
                 self._sealer.wait()
             return
+        key = self._cluster_key()
         if self.writer.options.buffered:
             if self._sealer is not None:
-                self.builder = self._sealer.submit(self.builder)
+                self.builder = self._sealer.submit(self.builder, key)
             else:
-                self.writer._commit_cluster(self.builder.seal(self.writer._pool))
+                self.writer._commit_cluster(
+                    self.builder.seal(self.writer._pool, key), key)
         else:
             for payload, desc, ns in self.builder.drain_rest(self.writer._pool):
-                self._page_buf.append(self.writer._commit_page(payload, desc, ns))
+                self._page_buf.append(
+                    self.writer._commit_page(payload, desc, ns, key))
             zm = self.builder.take_zonemaps()
             n_entries, n_elements, unc = self.builder.finish_unbuffered()
             self.writer._commit_cluster_meta_unbuffered(
@@ -851,8 +886,6 @@ class FillContext:
         # only mark closed after a successful drain: a failed close stays
         # retryable and is never silently dropped by ParallelWriter.close
         self._ctx_closed = True
-        self.writer.stats.add_fill_ns(self._fill_ns)
-        self._fill_ns = 0
 
 
 class ParallelWriter(_WriterBase):
@@ -873,25 +906,22 @@ class ParallelWriter(_WriterBase):
         self._ctx_lock = threading.Lock()
 
     def create_fill_context(self) -> FillContext:
-        ctx = FillContext(self)
         with self._ctx_lock:
+            ctx = FillContext(self, len(self._contexts))
             self._contexts.append(ctx)
         return ctx
 
-    def close(self) -> None:
-        if not self._closed:
-            # Flush (and drain the seal pipelines of) any contexts the
-            # producers did not close themselves.  One failing context
-            # must not stop the others from draining, nor leak the sink —
-            # the first error poisons finalization instead.
-            with self._ctx_lock:
-                for ctx in self._contexts:
-                    try:
-                        ctx.close()
-                    except BaseException as e:
-                        if self._commit_error is None:
-                            self._commit_error = e
-        super().close()
+    def _flush_producers(self) -> None:
+        # Flush (and drain the seal pipelines of) any contexts the
+        # producers did not close themselves.  One failing context
+        # must not stop the others from draining, nor leak the sink —
+        # the first error poisons finalization instead.
+        with self._ctx_lock:
+            for ctx in self._contexts:
+                try:
+                    ctx.close()
+                except BaseException as e:
+                    self._poison(e)
 
 
 # ---------------------------------------------------------------------------

@@ -34,6 +34,7 @@ import numpy as np
 
 from repro.core import RNTJReader
 from repro.core.reader import ReadOptions
+from repro.core.stats import span
 
 
 def _pack_cluster(vals, offs, ndocs: int, eos_id: int):
@@ -273,33 +274,41 @@ class PackedLoader:
             else:
                 self._flat = jnp.asarray(left)
                 self._pos = 0
+        n_batches = n_refills = 0
         while True:
-            avail = int(self._flat.shape[0]) - self._pos
-            if avail < need:
-                # Right-align the remainder inside a fixed (need,) carry
-                # window so the concatenated shape depends only on WHICH
-                # clusters this refill pulls (per-cluster constants), not
-                # on the drifting remainder length.  Shape drift here
-                # recompiles concatenate + the views jit on every refill,
-                # forever — the carry keeps steady state compile-free
-                # after the first epoch.
-                buf = self._flat[-need:]
-                total = avail
-                while total < need:
-                    vals, offs, ndocs, drop = next(stream)
-                    if drop:  # restore-only: pack, slice, plain concat
-                        chunk = pack(vals, offs, ndocs=ndocs,
-                                     eos_id=self.eos_id)[drop:]
-                        buf = jnp.concatenate([buf, chunk])
-                    else:
-                        buf = pack_carry(buf, vals, offs, ndocs=ndocs,
-                                         eos_id=self.eos_id)
-                    total += int(vals.shape[0]) + ndocs - drop
-                self._flat = buf
-                self._pos = need - avail
-            tokens, labels = views(self._flat, self._pos,
-                                   batch=self.batch, seq_len=self.seq_len)
-            self._pos += need
+            # one batch is the loader.next span; each cluster pulled for
+            # it, a loader.refill span inside (no span stays open across
+            # the yield)
+            with span("loader.next", n_batches):
+                avail = int(self._flat.shape[0]) - self._pos
+                if avail < need:
+                    # Right-align the remainder inside a fixed (need,)
+                    # carry window so the concatenated shape depends only
+                    # on WHICH clusters this refill pulls (per-cluster
+                    # constants), not on the drifting remainder length.
+                    # Shape drift here recompiles concatenate + the views
+                    # jit on every refill, forever — the carry keeps
+                    # steady state compile-free after the first epoch.
+                    buf = self._flat[-need:]
+                    total = avail
+                    while total < need:
+                        with span("loader.refill", n_refills):
+                            vals, offs, ndocs, drop = next(stream)
+                            if drop:  # restore-only: pack, slice, concat
+                                chunk = pack(vals, offs, ndocs=ndocs,
+                                             eos_id=self.eos_id)[drop:]
+                                buf = jnp.concatenate([buf, chunk])
+                            else:
+                                buf = pack_carry(buf, vals, offs, ndocs=ndocs,
+                                                 eos_id=self.eos_id)
+                        n_refills += 1
+                        total += int(vals.shape[0]) + ndocs - drop
+                    self._flat = buf
+                    self._pos = need - avail
+                tokens, labels = views(self._flat, self._pos,
+                                       batch=self.batch, seq_len=self.seq_len)
+                self._pos += need
+            n_batches += 1
             yield {"tokens": tokens, "labels": labels}
 
     def close(self) -> None:

@@ -8,8 +8,10 @@ Fault-tolerance behaviour:
     restarted run continues on the exact next batch;
   * on construction the loop restores the latest committed checkpoint if
     one exists (crash-restart is the default path, not a special case);
-  * straggler mitigation: per-step wall time is tracked against a rolling
-    median; a step slower than ``straggler_factor``x the median fires the
+  * straggler mitigation: per-step wall time (input, placement, step and
+    loss fetch) is tracked against the median of the last
+    ``STRAGGLER_WINDOW`` steps; a step slower than ``straggler_factor``x
+    that median fires the
     ``on_straggler`` hook (at fleet scale: re-shard that host's data and
     deprioritize it; here the hook records the event and the test asserts
     the detection fires).
@@ -17,19 +19,23 @@ Fault-tolerance behaviour:
 
 from __future__ import annotations
 
-import time
-from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional
+from collections import deque
+from dataclasses import dataclass
+from typing import Callable, Dict, List, Optional
 
 import jax
 import numpy as np
 
 from repro.ckpt import CheckpointManager
+from repro.core.stats import span
 from repro.models.registry import ModelBundle
 from repro.pipeline import PackedLoader
 
 from .optimizer import AdamW, make_optimizer
 from .step import init_train_state, make_train_step
+
+#: steps whose median the straggler check compares against
+STRAGGLER_WINDOW = 20
 
 
 @dataclass
@@ -71,7 +77,7 @@ class TrainLoop:
         self.mgr = CheckpointManager(ckpt_dir, keep=self.config.keep_ckpts)
         self.on_straggler = on_straggler
         self.history: List[StepEvent] = []
-        self._step_times: List[float] = []
+        self._step_times: deque = deque(maxlen=STRAGGLER_WINDOW)
 
         jitted_for, shardings = make_train_step(
             bundle, mesh, optimizer=self.optimizer,
@@ -145,31 +151,46 @@ class TrainLoop:
         return jax.device_put(batch, self._batch_sh)
 
     def run(self, steps: Optional[int] = None) -> List[StepEvent]:
+        """Run ``steps`` steps.  Each is the ``train.step`` span (a
+        ``StepTraceAnnotation`` numbered by the step it completes), with
+        ``train.next_batch``, ``train.place``, ``train.dispatch``,
+        ``train.loss_wait`` and ``train.bookkeeping`` inside it."""
         steps = steps if steps is not None else self.config.steps
         batches = self.loader.batches()
         target = self.step + steps
         while self.step < target:
-            jb = self.place(next(batches))
-            t0 = time.perf_counter()
-            self.params, self.opt_state, self.err_state, metrics = self._step_fn(
-                self.params, self.opt_state, self.err_state, jb)
-            loss = float(metrics["loss"])
-            wall = time.perf_counter() - t0
-            self.step += 1
-
-            straggler = False
-            if len(self._step_times) >= 5:
-                med = float(np.median(self._step_times[-20:]))
-                straggler = wall > self.config.straggler_factor * med
-            self._step_times.append(wall)
-            ev = StepEvent(self.step, loss, wall, straggler)
-            self.history.append(ev)
-            if straggler and self.on_straggler:
-                self.on_straggler(ev)
-            if self.step % self.config.log_every == 0:
-                print(f"step {self.step:6d}  loss {loss:8.4f}  {wall*1e3:8.1f} ms",
-                      flush=True)
-            if self.step % self.config.ckpt_every == 0:
-                self._save()
+            k = self.step + 1
+            with span("train.step", k, timed=True, step=True) as st:
+                with span("train.next_batch", k):
+                    batch = next(batches)
+                with span("train.place", k):
+                    jb = self.place(batch)
+                with span("train.dispatch", k):
+                    (self.params, self.opt_state, self.err_state,
+                     metrics) = self._step_fn(self.params, self.opt_state,
+                                              self.err_state, jb)
+                with span("train.loss_wait", k, timed=True) as lw:
+                    loss = float(metrics["loss"])
+                self.step = k
+                with span("train.bookkeeping", k):
+                    self._bookkeep(loss, (lw.t1 - st.t0) / 1e9)
         self.mgr.wait()
         return self.history
+
+    def _bookkeep(self, loss: float, wall: float) -> None:
+        """Straggler check, history, logging and checkpointing after a
+        step whose input, placement, dispatch and loss took ``wall``."""
+        straggler = False
+        if len(self._step_times) >= 5:
+            med = float(np.median(self._step_times))
+            straggler = wall > self.config.straggler_factor * med
+        self._step_times.append(wall)
+        ev = StepEvent(self.step, loss, wall, straggler)
+        self.history.append(ev)
+        if straggler and self.on_straggler:
+            self.on_straggler(ev)
+        if self.step % self.config.log_every == 0:
+            print(f"step {self.step:6d}  loss {loss:8.4f}  {wall*1e3:8.1f} ms",
+                  flush=True)
+        if self.step % self.config.ckpt_every == 0:
+            self._save()
