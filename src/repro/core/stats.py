@@ -688,9 +688,24 @@ def _on_compile(event: str, duration: float, fun_name=None, **_kw) -> None:
     if name is None or not _is_enabled():
         return
     t1 = _ns()
+    _keep(name, fun_name, t1 - int(duration * 1e9), t1)
+
+
+def _keep(name: str, key, t0: int, t1: int) -> None:
+    """Keep a record that was not timed as a span, under the span open on
+    this thread."""
     st = _stack()
-    _log.add(SpanRecord(next(_ids), name, fun_name, t1 - int(duration * 1e9),
-                        t1, st[-1] if st else None, threading.get_ident()))
+    _log.add(SpanRecord(next(_ids), name, key, t0, t1, st[-1] if st else None,
+                        threading.get_ident()))
+
+
+def note(name: str, key=None) -> None:
+    """Keep a zero-length record ``name`` (``key`` says what happened) under
+    the span open on this thread, like a ``compile`` record: only while a
+    profiler session is active, and only in memory."""
+    if profiler_active():
+        t = _ns()
+        _keep(name, key, t, t)
 
 
 def records() -> List[SpanRecord]:

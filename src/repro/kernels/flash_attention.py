@@ -1,5 +1,17 @@
-"""Pallas TPU flash attention (train / prefill).
+"""Pallas TPU flash attention: the train kernel and a forward-only kernel.
 
+:func:`flash_attention_train` is the attention the differentiated train
+step runs on a TPU: the splash attention kernels shipped with jax
+(``jax.experimental.pallas.ops.tpu.splash_attention``), Pallas in the
+forward and in the backward (one fused kernel for dq, dk and dv), so the
+``(S, S)`` scores never reach HBM in the forward, its recompute under
+remat, or the backward.  One MQA kernel per kv group over a causal
+mask, vmapped over batch and kv groups; bf16 operands into the MXU with
+f32 accumulation and f32 softmax statistics.  ``ops.flash_attention``
+routes to it where the shapes allow (see there).
+
+:func:`flash_attention` is the repo's own forward-only kernel (no VJP),
+run only when asked for (``use_pallas=True``, e.g. by serving).
 Online-softmax tiled attention with GQA/MQA head grouping, causal masking
 and optional sliding-window (SWA) masking.  Grid is
 (batch, q_head, q_block, kv_block) with the kv dimension innermost —
@@ -20,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -144,3 +157,60 @@ def flash_attention(
         interpret=interpret,
     )(qp, kp, vp)
     return out[:, :, :sq]
+
+
+# ---------------------------------------------------------------------------
+# the train kernel (forward and backward in Pallas)
+
+#: the train kernel's tile along both sequence axes, in order of
+#: preference: the first that divides S.  Tuned at S 2048, d 64 on a v5e,
+#: where 1024 with the fused backward (dq, dk and dv from one kernel) ran
+#: a layer's forward, remat forward and backward fastest of 128/256/512/
+#: 1024, fused or not
+TRAIN_BLOCKS = (1024, 512, 256, 128)
+#: head dims the train kernel is compiled and tested for
+TRAIN_HEAD_DIMS = (64, 128)
+
+
+def train_block(s: int) -> Optional[int]:
+    """The train kernel's block at sequence length ``s``; None when no
+    block divides ``s``."""
+    return next((blk for blk in TRAIN_BLOCKS if s % blk == 0), None)
+
+
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(q_per_kv: int, s: int, block: int, interpret: bool):
+    mask = splash.MultiHeadMask([splash.CausalMask((s, s))] * q_per_kv)
+    sizes = splash.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=block,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+        use_fused_bwd_kernel=True)
+    # the mask tables are built as constants, not as tracers of the trace
+    # that first asks for the kernel
+    with jax.ensure_compile_time_eval():
+        return splash.make_splash_mqa_single_device(
+            mask, block_sizes=sizes, interpret=interpret)
+
+
+def flash_attention_train(
+    q: jax.Array,                 # (B, H, S, D)
+    k: jax.Array,                 # (B, G, S, D)
+    v: jax.Array,                 # (B, G, S, D)
+    scale: Optional[float] = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """Causal GQA attention with a Pallas forward and backward.
+
+    q head ``h`` attends kv head ``h // (H // G)``, as in
+    ``ref.flash_attention_ref``.  S must be a multiple of a block
+    (:func:`train_block`)."""
+    b, h, s, d = q.shape
+    g = k.shape[1]
+    assert h % g == 0 and k.shape == v.shape == (b, g, s, d), \
+        (q.shape, k.shape)
+    block = train_block(s)
+    assert block, s
+    scale = scale if scale is not None else float(1.0 / np.sqrt(d))
+    kern = _splash_kernel(h // g, s, block, interpret)
+    qg = (q * scale).reshape(b, g, h // g, s, d)
+    return jax.vmap(jax.vmap(kern))(qg, k, v).reshape(b, h, s, d)

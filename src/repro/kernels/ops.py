@@ -5,10 +5,10 @@ through ``interpret=True`` when forced, and the ``ref`` oracles compile
 through XLA otherwise.  Model code calls these wrappers only.
 
 ``use_pallas``: None = auto (pallas on TPU, ref elsewhere), True = force
-pallas (interpret on CPU), False = force ref.  One exception to auto:
-:func:`flash_attention` has no VJP, and the model code that calls it is
-differentiated by the train step, so its auto is the XLA attention (see
-there).
+pallas (interpret on CPU), False = force ref.  :func:`flash_attention`
+refines auto by shape: on a TPU it runs the train kernel (Pallas forward
+and backward, so the differentiated train step can take it) where the
+call's shapes allow, and the XLA attention everywhere else (see there).
 
 This module also owns :class:`KernelDispatch` — the ONE auto/numpy/pallas
 backend selector shared by every host-facing encode/decode kernel
@@ -172,24 +172,92 @@ def decode_offset_pages(planes, use_pallas: Optional[bool] = None, **kw):
     return ref.decode_offset_pages_ref(planes)
 
 
+class PathCounter:
+    """Calls of one kernel family lowered on each of its paths.
+
+    ``calls[path]`` counts them, as :attr:`KernelDispatch.calls` counts
+    the calls that ran a kernel; each also leaves a ``<name>.<path>``
+    record in ``core.stats`` (key: the call's shapes and dtype), kept
+    like the ``compile`` records while a profiler session is active.
+    A jitted caller lowers once per trace, so the counts are traces."""
+
+    def __init__(self, name: str, paths) -> None:
+        self.name = name
+        self.calls = dict.fromkeys(paths, 0)
+        self._lock = threading.Lock()
+
+    def count(self, path: str, key) -> None:
+        with self._lock:
+            self.calls[path] += 1
+        from ..core import stats
+
+        stats.note(f"{self.name}.{path}", key)
+
+
+#: the paths of :func:`flash_attention`: the train kernel, the
+#: forward-only kernel asked for with ``use_pallas=True``, the XLA attention
+ATTENTION = PathCounter("attention", ("kernel", "forward_kernel", "xla"))
+
+
+def _single_device() -> bool:
+    """No sharding rules over more than one device are active: a Pallas
+    call is not partitioned across a mesh."""
+    from ..distributed.sharding import current_rules
+
+    rules = current_rules()
+    return rules is None or rules.mesh.size == 1
+
+
+def _train_kernel_fits(q, k, v, causal, window) -> bool:
+    """The train kernel serves this attention call: a TPU, one device,
+    a causal mask without a window, Sq == Sk on a block multiple, and one
+    head dim and dtype the kernel supports for q, k and v."""
+    if not (_on_tpu() and causal and window is None):
+        return False
+    from .flash_attention import TRAIN_HEAD_DIMS, train_block
+
+    s, d = q.shape[2], q.shape[3]
+    return (k.shape[2] == s and train_block(s) is not None
+            and d in TRAIN_HEAD_DIMS and k.shape[3] == v.shape[3] == d
+            and q.dtype == k.dtype == v.dtype and _single_device())
+
+
 def flash_attention(q, k, v, causal=True, window=None, scale=None,
                     use_pallas: Optional[bool] = None, impl: str = "ref", **kw):
     """Causal (optionally sliding-window) GQA attention.
 
-    The rule: the Pallas kernel (``kernels/flash_attention.py``) is
-    forward-only — it has no VJP — so it runs only when asked for with
-    ``use_pallas=True``.  Auto (``None``) compiles the XLA attention
-    selected by ``impl`` on every backend, TPU included, which is what
-    the model code and hence the differentiated train step use.
+    The rule, on the input and not on a knob:
 
-    impl: "ref" (naive softmax — the paper-faithful baseline shape) or
-    "chunked" (online-softmax scan over kv blocks — the §Perf variant)."""
+    - auto (``None``) on a TPU runs the train kernel
+      (``kernels/flash_attention.flash_attention_train``: Pallas forward
+      and backward, the scores never in HBM) when
+      :func:`_train_kernel_fits`: causal, no window, ``Sq == Sk`` a
+      multiple of the kernel's block, q/k/v sharing a supported head dim
+      and dtype, no multi-device mesh.  The train step takes it;
+    - every other auto call (prefill or decode with ``Sq != Sk``, MLA's
+      ``dv != d``, a sliding window, a mesh, any CPU backend) and
+      ``use_pallas=False`` compile the XLA attention selected by
+      ``impl``: "ref" (naive softmax — the paper-faithful baseline shape)
+      or "chunked" (online-softmax scan over kv blocks);
+    - ``use_pallas=True`` runs the forward-only Pallas kernel
+      (``kernels/flash_attention.flash_attention``, no VJP; interpret
+      mode off a TPU), as serving asks.
+
+    :data:`ATTENTION` counts each call on its path."""
+    key = (tuple(q.shape), tuple(k.shape), tuple(v.shape), str(q.dtype))
     if use_pallas:
+        ATTENTION.count("forward_kernel", key)
         _, interp = _resolve(True)
         from .flash_attention import flash_attention as kern
 
         return kern(q, k, v, causal=causal, window=window,
                     scale=scale, interpret=interp, **kw)
+    if use_pallas is None and _train_kernel_fits(q, k, v, causal, window):
+        ATTENTION.count("kernel", key)
+        from .flash_attention import flash_attention_train
+
+        return flash_attention_train(q, k, v, scale=scale)
+    ATTENTION.count("xla", key)
     from . import ref
 
     if impl == "chunked":

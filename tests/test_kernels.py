@@ -5,6 +5,8 @@ against the numpy host implementations in repro.core.encoding (the writer's
 actual serialization path must be bit-identical to the TPU kernels).
 """
 
+from types import SimpleNamespace
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,10 +14,12 @@ import pytest
 from _hypothesis_compat import given, settings, strategies as st
 
 from repro.core import encoding as E
-from repro.kernels import ref
+from repro.core import stats
+from repro.kernels import ops, ref
 from repro.kernels.byteshuffle import byteshuffle
 from repro.kernels.decode_attention import decode_attention
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import (flash_attention,
+                                          flash_attention_train)
 from repro.kernels.mamba2_ssd import mamba2_ssd
 from repro.kernels.offsets_scan import offsets_scan
 from repro.kernels.rwkv6_scan import rwkv6_scan
@@ -102,6 +106,123 @@ def test_flash_attention_matches_naive_softmax():
     out = flash_attention(q, k, v, causal=True, block_q=32, block_k=32,
                           interpret=True)
     np.testing.assert_allclose(out, expect, atol=2e-5, rtol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the train kernel (Pallas forward and backward) and its dispatch rule
+
+
+def _attn_grads(attn, q, k, v, w):
+    """Output and (dq, dk, dv) of sum(attn(q, k, v) * w)."""
+    def loss(q, k, v):
+        return jnp.sum(attn(q, k, v).astype(jnp.float32) * w)
+
+    return attn(q, k, v), jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("h,g,s,d", [
+    (3, 1, 256, 64), (4, 4, 256, 64), (6, 2, 256, 64),
+    (3, 1, 512, 64), (4, 4, 512, 64), (6, 2, 512, 64),
+    (6, 2, 256, 128),
+], ids=["gqa3-256", "mha4-256", "gqa3x2-256", "gqa3-512", "mha4-512",
+        "gqa3x2-512", "gqa3x2-256-d128"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_flash_train_kernel_matches_ref_with_grads(h, g, s, d, dtype):
+    """Output and dq, dk, dv of the train kernel (interpret mode) against
+    the XLA attention in float32; bf16 inputs round q, k, v, p and the
+    outputs to bf16, hence the looser bound."""
+    b = 1
+    q = jnp.asarray(RNG.normal(0, 1, (b, h, s, d)), dtype=dtype)
+    k = jnp.asarray(RNG.normal(0, 1, (b, g, s, d)), dtype=dtype)
+    v = jnp.asarray(RNG.normal(0, 1, (b, g, s, d)), dtype=dtype)
+    w = jnp.asarray(RNG.normal(0, 1, (b, h, s, d)), dtype=jnp.float32)
+    out, grads = _attn_grads(
+        lambda q, k, v: flash_attention_train(q, k, v, interpret=True),
+        q, k, v, w)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    with jax.default_matmul_precision("highest"):
+        want, want_grads = _attn_grads(
+            lambda q, k, v: ref.flash_attention_ref(q, k, v, causal=True),
+            *f32, w)
+    assert out.dtype == dtype and [x.dtype for x in grads] == [dtype] * 3
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    for got, exp in zip([out, *grads], [want, *want_grads]):
+        got = np.asarray(got, np.float32)
+        exp = np.asarray(exp, np.float32)
+        assert np.max(np.abs(got - exp)) <= tol * np.max(np.abs(exp))
+
+
+@pytest.fixture
+def attention_records(monkeypatch):
+    """Records kept as under a profiler session, counters from zero."""
+    assert stats._bind_jax()
+    monkeypatch.setattr(stats, "_is_enabled", lambda: True)
+    monkeypatch.setattr(ops.ATTENTION, "calls",
+                        dict.fromkeys(ops.ATTENTION.calls, 0))
+    stats.clear()
+    yield
+    stats.clear()
+
+
+# (q, k, v shapes, window, path on a TPU)
+SMOLLM = ((4, 15, 2048, 64), (4, 5, 2048, 64), (4, 5, 2048, 64))
+DISPATCH = {
+    "smollm": (SMOLLM, None, "kernel"),
+    "mha-256": (((2, 4, 256, 128),) * 3, None, "kernel"),
+    "sq!=sk": (((1, 4, 128, 64), (1, 2, 256, 64), (1, 2, 256, 64)), None,
+               "xla"),
+    "s-off-block": (((1, 4, 200, 64), (1, 2, 200, 64), (1, 2, 200, 64)),
+                    None, "xla"),
+    "dv!=d": (((1, 4, 256, 96), (1, 4, 256, 96), (1, 4, 256, 64)), None,
+              "xla"),
+    "head-dim-80": (((1, 4, 256, 80),) * 3, None, "xla"),
+    "window": (SMOLLM, 1024, "xla"),
+}
+
+
+@pytest.mark.parametrize("case", list(DISPATCH))
+def test_flash_attention_dispatch_rule(case, monkeypatch, attention_records):
+    """On a TPU the train kernel takes the causal Sq == Sk shapes it
+    supports; everything else stays on the XLA attention, and the counter
+    and the records name the path."""
+    shapes, window, path = DISPATCH[case]
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    q, k, v = (jax.ShapeDtypeStruct(x, jnp.bfloat16) for x in shapes)
+    out = jax.eval_shape(
+        lambda q, k, v: ops.flash_attention(q, k, v, causal=True,
+                                            window=window), q, k, v)
+    assert out.shape == shapes[0][:3] + shapes[2][3:]
+    assert ops.ATTENTION.calls == {"kernel": int(path == "kernel"),
+                                   "forward_kernel": 0,
+                                   "xla": int(path == "xla")}
+    recs = [r for r in stats.records() if r.name.startswith("attention.")]
+    assert [(r.name, r.key) for r in recs] == [
+        (f"attention.{path}", (*shapes, "bfloat16"))]
+
+
+@pytest.mark.parametrize("where", ["cpu", "mesh", "no-profiler"])
+def test_flash_attention_dispatch_off_tpu_and_on_a_mesh(where, monkeypatch,
+                                                        attention_records):
+    """The smollm shape stays on XLA on a CPU backend and under sharding
+    rules over several devices; without a profiler session the counter
+    still counts and no record is kept."""
+    if where != "cpu":
+        monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    if where == "mesh":
+        from repro.distributed import sharding
+
+        four = SimpleNamespace(mesh=SimpleNamespace(size=4))
+        monkeypatch.setattr(sharding, "current_rules", lambda: four)
+    if where == "no-profiler":
+        monkeypatch.setattr(stats, "_is_enabled", lambda: False)
+    q, k, v = (jax.ShapeDtypeStruct(x, jnp.bfloat16) for x in SMOLLM)
+    jax.eval_shape(lambda q, k, v: ops.flash_attention(q, k, v), q, k, v)
+    path = "kernel" if where == "no-profiler" else "xla"
+    calls = ops.ATTENTION.calls
+    assert calls[path] == 1 and sum(calls.values()) == 1
+    names = [r.name for r in stats.records()]
+    assert names == ([] if where == "no-profiler" else ["attention.xla"])
 
 
 # ---------------------------------------------------------------------------

@@ -94,10 +94,15 @@ def test_device_decode_offsets_compiles(one_chip, no_cache):
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "gradient"])
-def test_train_step_attention_compiles(one_chip, no_cache, grad):
-    """smollm-360m attention as the train step calls it: XLA attention by
-    the rule in ``ops.flash_attention`` (the Pallas kernel has no VJP),
-    forward and gradient, at batch 4 x 2048."""
+def test_train_step_attention_compiles(one_chip, no_cache, monkeypatch, grad):
+    """smollm-360m attention as the train step calls it, at batch 4 x 2048,
+    forward and gradient: on a TPU ``ops.flash_attention`` takes the train
+    kernel (Pallas forward and backward, interpret off), so the program
+    holds Mosaic kernels and no [4, 15, 2048, 2048] score tensor.  The
+    backend here is the CPU, so the test says it is a TPU."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ops.ATTENTION, "calls",
+                        dict.fromkeys(ops.ATTENTION.calls, 0))
     cfg = get_arch("smollm-360m")
     b, s, d = 4, 2048, cfg.resolved_head_dim
     q = jax.ShapeDtypeStruct((b, cfg.n_heads, s, d), jnp.bfloat16,
@@ -114,5 +119,9 @@ def test_train_step_attention_compiles(one_chip, no_cache, grad):
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else attn
     hlo = _hlo(fn, q, kv, kv)
-    assert KERNEL not in hlo  # XLA attention: no Mosaic kernel in the step
-    assert "dot" in hlo or "convolution" in hlo
+    calls = ops.ATTENTION.calls
+    assert calls["xla"] == 0 and calls["kernel"] > 0
+    # the forward kernel, and in the gradient the fused backward kernel too
+    assert hlo.count(KERNEL) >= (2 if grad else 1)
+    scores = f"{b},{cfg.n_heads},{s},{s}"
+    assert scores not in hlo
