@@ -13,18 +13,31 @@ from typing import Optional, Tuple
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int                    # routed experts the router scores
     top_k: int
     n_shared: int = 0                 # shared (always-on) experts
-    capacity_factor: float = 1.25
+    d_ff: int = 0                     # expert width; 0 -> ArchConfig.d_ff
+    capacity_factor: float = 1.25     # the multi-device capacity path only
     router_dtype: str = "float32"
+    scoring: str = "softmax"          # softmax | sigmoid (DeepSeek-V3)
+    routed_scale: float = 1.0         # routed_scaling_factor
+    bias_update: float = 0.0          # gamma of the aux-loss-free correction
+                                      # bias (0: no bias)
+    aux: str = "switch"               # switch | seq (sequence-wise balance)
+    aux_weight: float = 0.01
+    n_held: int = 0                   # experts held here (0 -> all), the
+    first_held: int = 0               # chip's share under expert parallelism
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclass(frozen=True)
 class MLAConfig:
     """Multi-head Latent Attention (DeepSeek-V2 / MiniCPM3)."""
 
-    q_rank: int          # low-rank query compression
+    q_rank: Optional[int]  # low-rank query compression (None: a direct W_q)
     kv_rank: int         # low-rank kv compression (this is what decode caches)
     d_nope: int          # per-head non-rotary q/k dim
     d_rope: int          # shared rotary dim
@@ -57,6 +70,7 @@ class ArchConfig:
     window: Optional[int] = None      # sliding-window attention
     qk_norm: bool = False             # chameleon-style qk layernorm
     moe: Optional[MoEConfig] = None
+    first_k_dense: int = 0            # leading dense layers before the MoE stack
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
     shared_attn_every: int = 0        # zamba2: shared attn block period

@@ -168,8 +168,9 @@ def flash_attention(
 #: a layer's forward, remat forward and backward fastest of 128/256/512/
 #: 1024, fused or not
 TRAIN_BLOCKS = (1024, 512, 256, 128)
-#: head dims the train kernel is compiled and tested for
-TRAIN_HEAD_DIMS = (64, 128)
+#: (q/k head dim, v head dim) pairs the train kernel is compiled and
+#: tested for; (192, 128) is MLA's (nope 128 + rope 64, v 128)
+TRAIN_HEAD_DIMS = ((64, 64), (128, 128), (192, 128))
 
 
 def train_block(s: int) -> Optional[int]:
@@ -195,7 +196,7 @@ def _splash_kernel(q_per_kv: int, s: int, block: int, interpret: bool):
 def flash_attention_train(
     q: jax.Array,                 # (B, H, S, D)
     k: jax.Array,                 # (B, G, S, D)
-    v: jax.Array,                 # (B, G, S, D)
+    v: jax.Array,                 # (B, G, S, Dv)
     scale: Optional[float] = None,
     interpret: bool = False,
 ) -> jax.Array:
@@ -203,14 +204,17 @@ def flash_attention_train(
 
     q head ``h`` attends kv head ``h // (H // G)``, as in
     ``ref.flash_attention_ref``.  S must be a multiple of a block
-    (:func:`train_block`)."""
+    (:func:`train_block`).  The value head dim may differ from the
+    query/key one (MLA); the output takes the value's."""
     b, h, s, d = q.shape
-    g = k.shape[1]
-    assert h % g == 0 and k.shape == v.shape == (b, g, s, d), \
-        (q.shape, k.shape)
+    g, dv = k.shape[1], v.shape[3]
+    assert h % g == 0 and k.shape == (b, g, s, d) \
+        and v.shape == (b, g, s, dv), (q.shape, k.shape, v.shape)
     block = train_block(s)
     assert block, s
-    scale = scale if scale is not None else float(1.0 / np.sqrt(d))
+    # a Python float, so that q keeps its dtype (a NumPy scalar would
+    # promote bf16 to f32)
+    scale = float(scale if scale is not None else 1.0 / np.sqrt(d))
     kern = _splash_kernel(h // g, s, block, interpret)
     qg = (q * scale).reshape(b, g, h // g, s, d)
-    return jax.vmap(jax.vmap(kern))(qg, k, v).reshape(b, h, s, d)
+    return jax.vmap(jax.vmap(kern))(qg, k, v).reshape(b, h, s, dv)

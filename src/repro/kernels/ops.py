@@ -210,15 +210,16 @@ def _single_device() -> bool:
 
 def _train_kernel_fits(q, k, v, causal, window) -> bool:
     """The train kernel serves this attention call: a TPU, one device,
-    a causal mask without a window, Sq == Sk on a block multiple, and one
-    head dim and dtype the kernel supports for q, k and v."""
+    a causal mask without a window, Sq == Sk on a block multiple, a pair
+    of q/k and v head dims the kernel supports (MLA's 192/128 among
+    them), and one dtype for q, k and v."""
     if not (_on_tpu() and causal and window is None):
         return False
     from .flash_attention import TRAIN_HEAD_DIMS, train_block
 
     s, d = q.shape[2], q.shape[3]
     return (k.shape[2] == s and train_block(s) is not None
-            and d in TRAIN_HEAD_DIMS and k.shape[3] == v.shape[3] == d
+            and k.shape[3] == d and (d, v.shape[3]) in TRAIN_HEAD_DIMS
             and q.dtype == k.dtype == v.dtype and _single_device())
 
 
@@ -232,10 +233,11 @@ def flash_attention(q, k, v, causal=True, window=None, scale=None,
       (``kernels/flash_attention.flash_attention_train``: Pallas forward
       and backward, the scores never in HBM) when
       :func:`_train_kernel_fits`: causal, no window, ``Sq == Sk`` a
-      multiple of the kernel's block, q/k/v sharing a supported head dim
-      and dtype, no multi-device mesh.  The train step takes it;
-    - every other auto call (prefill or decode with ``Sq != Sk``, MLA's
-      ``dv != d``, a sliding window, a mesh, any CPU backend) and
+      multiple of the kernel's block, a supported pair of q/k and v head
+      dims, one dtype, no multi-device mesh.  The train step takes it;
+    - every other auto call (prefill or decode with ``Sq != Sk``, head
+      dims outside the kernel's pairs, a sliding window, a mesh, any CPU
+      backend) and
       ``use_pallas=False`` compile the XLA attention selected by
       ``impl``: "ref" (naive softmax — the paper-faithful baseline shape)
       or "chunked" (online-softmax scan over kv blocks);
@@ -265,6 +267,43 @@ def flash_attention(q, k, v, causal=True, window=None, scale=None,
                                            window=window, scale=scale)
     return ref.flash_attention_ref(q, k, v, causal=causal, window=window,
                                    scale=scale)
+
+
+#: the paths of the MoE layer (``models/moe.py``): the dropless layer's
+#: grouped matmuls as the Pallas ``gmm`` (megablox, shipped with jax), and
+#: the GShard capacity dispatch of a multi-device mesh.  One count per MoE
+#: layer lowered
+MOE = PathCounter("moe", ("gmm", "capacity"))
+#: the dropless layer's row buffer is a multiple of this: the gmm row tile
+GROUPED_ROWS = 512
+#: gmm tiles (rows, contraction, output columns), the fastest of five
+#: measured at the moonlight cell's shapes on a v5e, where gmm ran the
+#: held experts' SwiGLU, forward and gradient, in 12.1 ms against
+#: ``jax.lax.ragged_dot``'s 16.2 ms (PERF.md)
+GMM_TILING = (512, 512, 1024)
+
+
+def moe_path() -> str:
+    """The MoE layer's path: ``capacity`` under a multi-device mesh, else
+    the dropless layer's ``gmm``."""
+    return "gmm" if _single_device() else "capacity"
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """``(M, K) x (G, K, N) -> (M, N)`` in ``lhs``'s dtype (f32
+    accumulation): rows ``sum(group_sizes[:i])`` onward, ``group_sizes[i]``
+    of them, times ``rhs[i]``.  Rows past ``sum(group_sizes)`` are left
+    undefined (the caller masks them).  The Pallas ``gmm``, a custom VJP
+    whose weight gradient is the ``tgmm`` kernel; interpret mode off a
+    TPU."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    m, k = lhs.shape
+    n = rhs.shape[2]
+    tiling = (min(GMM_TILING[0], m), min(GMM_TILING[1], k),
+              min(GMM_TILING[2], n))
+    return megablox.gmm(lhs, rhs, group_sizes, lhs.dtype, tiling,
+                        interpret=not _on_tpu())
 
 
 def decode_attention(q, k, v, length=None, window=None, scale=None,

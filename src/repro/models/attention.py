@@ -145,7 +145,8 @@ def _masked_decode(q, k, v, n_valid, cache_len):
 # ---------------------------------------------------------------------------
 # MLA (Multi-head Latent Attention, MiniCPM3 / DeepSeek-V2)
 #
-# Queries:  q = W_uq norm(W_dq x)   per head split into (d_nope | d_rope)
+# Queries:  q = W_uq norm(W_dq x), or q = W_q x without query compression
+#           (q_rank None, DeepSeek-V3 / Moonlight); per head (d_nope | d_rope)
 # KV:       c = norm(W_dkv x)  (kv_rank)  +  k_rope = W_kr x (d_rope, shared)
 #           k_nope = W_uk c ; v = W_uv c  per head
 # The decode cache stores ONLY (c, k_rope): rank+d_rope floats per token.
@@ -157,10 +158,14 @@ def mla_init(rng, cfg: ArchConfig) -> Dict:
     dt = pdtype(cfg)
     ks = jax.random.split(rng, 8)
     qd = m.d_nope + m.d_rope
+    if m.q_rank is None:
+        q = {"wq": dense_init(ks[0], d, h * qd, dt)}
+    else:
+        q = {"w_dq": dense_init(ks[0], d, m.q_rank, dt),
+             "q_norm": jnp.ones((m.q_rank,), dt),
+             "w_uq": dense_init(ks[1], m.q_rank, h * qd, dt)}
     return {
-        "w_dq": dense_init(ks[0], d, m.q_rank, dt),
-        "q_norm": jnp.ones((m.q_rank,), dt),
-        "w_uq": dense_init(ks[1], m.q_rank, h * qd, dt),
+        **q,
         "w_dkv": dense_init(ks[2], d, m.kv_rank, dt),
         "kv_norm": jnp.ones((m.kv_rank,), dt),
         "w_kr": dense_init(ks[3], d, m.d_rope, dt),
@@ -176,10 +181,13 @@ def _mla_qckr(params, x, cfg, positions):
     b, t, _ = x.shape
     h = cfg.n_heads
     x = x.astype(dt)
-    cq = rms_norm(jnp.einsum("btd,dr->btr", x, params["w_dq"].astype(dt)),
-                  params["q_norm"], cfg.norm_eps)
-    q = jnp.einsum("btr,rk->btk", cq, params["w_uq"].astype(dt)).reshape(
-        b, t, h, m.d_nope + m.d_rope)
+    if m.q_rank is None:
+        q = jnp.einsum("btd,dk->btk", x, params["wq"].astype(dt))
+    else:
+        cq = rms_norm(jnp.einsum("btd,dr->btr", x, params["w_dq"].astype(dt)),
+                      params["q_norm"], cfg.norm_eps)
+        q = jnp.einsum("btr,rk->btk", cq, params["w_uq"].astype(dt))
+    q = q.reshape(b, t, h, m.d_nope + m.d_rope)
     q_nope, q_rope = q[..., : m.d_nope], q[..., m.d_nope:]
     q_rope = apply_rope(q_rope.swapaxes(1, 2), positions[:, None],
                         cfg.rope_theta).swapaxes(1, 2)

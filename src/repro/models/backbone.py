@@ -60,22 +60,23 @@ def block_init(rng, cfg: ArchConfig) -> Dict:
 
 
 def block_apply(p: Dict, x: jax.Array, cfg: ArchConfig,
-                positions: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """-> (x, aux_loss)."""
+                positions: jax.Array) -> Tuple[jax.Array, jax.Array, Dict]:
+    """-> (x, aux_loss, MoE stats: ``moe.moe_apply``'s, or {})."""
     aux = jnp.zeros((), jnp.float32)
     if cfg.family == "ssm" and cfg.ssm.kind == "rwkv6":
-        return R6.rwkv6_block_apply(p, x, cfg, positions), aux
+        return R6.rwkv6_block_apply(p, x, cfg, positions), aux, {}
     if cfg.family == "hybrid":
-        return M2.mamba2_block_apply(p, x, cfg, positions), aux
+        return M2.mamba2_block_apply(p, x, cfg, positions), aux, {}
     xn = rms_norm(x, p["norm1"], cfg.norm_eps)
     attn = A.mla_apply if cfg.attention == "mla" else A.gqa_apply
     x = x + attn(p["attn"], xn, cfg, positions)
     xn = rms_norm(x, p["norm2"], cfg.norm_eps)
+    stats = {}
     if cfg.moe is not None:
-        y, aux = MOE.moe_apply(p["moe"], xn, cfg)
+        y, aux, stats = MOE.moe_apply(p["moe"], xn, cfg)
     else:
         y = mlp_apply(p["mlp"], xn, cfg)
-    return shard(x + y, "dp", "sp", None), aux
+    return shard(x + y, "dp", "sp", None), aux, stats
 
 
 def block_init_cache(cfg: ArchConfig, batch: int, max_len: int, dtype) -> Dict:
@@ -100,7 +101,7 @@ def block_decode(p: Dict, x: jax.Array, cfg: ArchConfig, cache: Dict,
     x = x + y
     xn = rms_norm(x, p["norm2"], cfg.norm_eps)
     if cfg.moe is not None:
-        y, _ = MOE.moe_apply(p["moe"], xn, cfg)
+        y, _, _ = MOE.moe_apply(p["moe"], xn, cfg)
     else:
         y = mlp_apply(p["mlp"], xn, cfg)
     return x + y, new_cache
@@ -123,7 +124,7 @@ def _finish_block(p, x, attn_out, cfg):
     x = x + attn_out
     xn = rms_norm(x, p["norm2"], cfg.norm_eps)
     if cfg.moe is not None:
-        y, _ = MOE.moe_apply(p["moe"], xn, cfg)
+        y, _, _ = MOE.moe_apply(p["moe"], xn, cfg)
     else:
         y = mlp_apply(p["mlp"], xn, cfg)
     return x + y
@@ -230,11 +231,29 @@ def _mamba2_prefill(p, x, cfg):
 # model init
 
 
+def dense_cfg(cfg: ArchConfig) -> ArchConfig:
+    """The config of the leading dense layers (``first_k_dense``): the
+    same block with the dense MLP of width ``d_ff``."""
+    return cfg.with_(moe=None)
+
+
+def _stacks(cfg: ArchConfig):
+    """(params key, layer config) of each scanned stack, in order: the
+    leading dense layers (where the config has them), then the rest."""
+    if cfg.first_k_dense:
+        return [("dense_layers", dense_cfg(cfg)), ("layers", cfg)]
+    return [("layers", cfg)]
+
+
 def init_params(rng, cfg: ArchConfig) -> Dict:
     k_embed, k_layers, k_shared = jax.random.split(rng, 3)
     params: Dict[str, Any] = {"embedding": embedding_init(k_embed, cfg)}
     layer_keys = jax.random.split(k_layers, cfg.n_layers)
-    params["layers"] = jax.vmap(lambda k: block_init(k, cfg))(layer_keys)
+    k0 = cfg.first_k_dense
+    if k0:
+        params["dense_layers"] = jax.vmap(
+            lambda k: block_init(k, dense_cfg(cfg)))(layer_keys[:k0])
+    params["layers"] = jax.vmap(lambda k: block_init(k, cfg))(layer_keys[k0:])
     if cfg.shared_attn_every:
         shared_cfg = _shared_attn_cfg(cfg)
         ks1, ks2 = jax.random.split(k_shared)
@@ -312,15 +331,18 @@ def _run_stack(body, carry, stacked, cfg: ArchConfig):
 
 
 def _scan_layers(params, x, cfg: ArchConfig, positions):
-    """(x, total_aux) after the layer stack."""
+    """(x, total_aux, per-layer MoE stats of the last stack) after the
+    layer stacks."""
 
-    def body(carry, layer_p):
-        x, aux = carry
-        x, a = block_apply(layer_p, x, cfg, positions)
-        return (x, aux + a), None
+    def body_for(layer_cfg):
+        def body(carry, layer_p):
+            x, aux = carry
+            x, a, stats = block_apply(layer_p, x, layer_cfg, positions)
+            return (x, aux + a), stats
 
-    if cfg.remat:
-        body = jax.checkpoint(body, prevent_cse=False)
+        return jax.checkpoint(body, prevent_cse=False) if cfg.remat else body
+
+    body = body_for(cfg)
 
     if cfg.shared_attn_every:
         n_groups = cfg.n_layers // cfg.shared_attn_every
@@ -332,28 +354,71 @@ def _scan_layers(params, x, cfg: ArchConfig, positions):
             )
             (x, aux), _ = _run_stack(body, (x, aux), group_p, cfg)
             x = _shared_attn_apply(params["shared_attn"], x, cfg, positions)
-        return x, aux
+        return x, aux, {}
 
-    (x, aux), _ = _run_stack(
-        body, (x, jnp.zeros((), jnp.float32)), params["layers"], cfg
-    )
-    return x, aux
+    carry = (x, jnp.zeros((), jnp.float32))
+    stats = {}
+    for key, layer_cfg in _stacks(cfg):
+        carry, stats = _run_stack(body_for(layer_cfg), carry, params[key], cfg)
+    x, aux = carry
+    return x, aux, stats
+
+
+def _forward(params: Dict, tokens: jax.Array, cfg: ArchConfig):
+    b, s = tokens.shape[:2]
+    positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
+    x = embed_tokens(params["embedding"], tokens, cfg)
+    x, aux, stats = _scan_layers(params, x, cfg, positions)
+    return lm_logits(params["embedding"], x, cfg), aux, stats
 
 
 def forward(params: Dict, tokens: jax.Array, cfg: ArchConfig) -> Tuple[jax.Array, jax.Array]:
     """tokens (B,S[,Q]) -> (logits, aux_loss)."""
-    b, s = tokens.shape[:2]
-    positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
-    x = embed_tokens(params["embedding"], tokens, cfg)
-    x, aux = _scan_layers(params, x, cfg, positions)
-    return lm_logits(params["embedding"], x, cfg), aux
+    logits, aux, _ = _forward(params, tokens, cfg)
+    return logits, aux
 
 
 def loss_fn(params: Dict, batch: Dict, cfg: ArchConfig) -> Tuple[jax.Array, Dict]:
-    logits, aux = forward(params, batch["tokens"], cfg)
+    """Cross-entropy plus the MoE balance loss at the config's weight.
+    The metrics carry each MoE layer's assignment counts (``moe.picks``
+    over all experts, ``moe.held`` over the held ones) for
+    :func:`after_step`."""
+    logits, aux, stats = _forward(params, batch["tokens"], cfg)
     ce = cross_entropy_loss(logits, batch["labels"])
-    total = ce + 0.01 * aux
-    return total, {"ce": ce, "aux": aux}
+    weight = cfg.moe.aux_weight if cfg.moe is not None else 0.0
+    metrics = {"ce": ce, "aux": aux}
+    metrics.update({f"moe.{k}": v for k, v in stats.items()})
+    return ce + weight * aux, metrics
+
+
+def state_mask(params: Dict) -> Dict:
+    """A tree of bools like ``params``: True at the leaves that are the
+    model's state, not trained (the MoE routers' correction biases, which
+    :func:`after_step` moves).  The optimizer leaves them as they are."""
+    def one(path, _):
+        return bool(path) and getattr(path[-1], "key", None) == MOE.BIAS
+
+    return jax.tree_util.tree_map_with_path(one, params)
+
+
+def after_step(params: Dict, metrics: Dict, cfg: ArchConfig):
+    """After the optimizer's update: the routers' correction biases take
+    their step (aux-loss-free balancing, outside AdamW), and the step's
+    MoE counters (``moe.step_counters``) join ``metrics``, where the
+    per-layer ``moe.picks`` (L, E) stay and ``moe.held`` gives way to
+    them.  Anything else passes through."""
+    if "moe.picks" not in metrics:
+        return params, metrics
+    metrics = dict(metrics)
+    picks, held = metrics["moe.picks"], metrics.pop("moe.held")
+    moe_p = params["layers"]["moe"]
+    bias = None
+    if MOE.BIAS in moe_p:
+        bias = MOE.update_bias(moe_p[MOE.BIAS], picks, cfg)
+        params = {**params, "layers": {**params["layers"],
+                                       "moe": {**moe_p, MOE.BIAS: bias}}}
+    metrics.update(MOE.step_counters(held, bias))
+    return params, metrics
 
 
 def prefill(params: Dict, tokens: jax.Array, cfg: ArchConfig,
@@ -364,9 +429,14 @@ def prefill(params: Dict, tokens: jax.Array, cfg: ArchConfig,
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
     x = embed_tokens(params["embedding"], tokens, cfg)
 
-    def body(x, layer_p):
-        x, cache = block_prefill(layer_p, x, cfg, positions, max_len, cache_dtype)
-        return x, cache
+    def body_for(layer_cfg):
+        def body(x, layer_p):
+            return block_prefill(layer_p, x, layer_cfg, positions, max_len,
+                                 cache_dtype)
+
+        return body
+
+    body = body_for(cfg)
 
     if cfg.shared_attn_every:
         every = cfg.shared_attn_every
@@ -387,8 +457,10 @@ def prefill(params: Dict, tokens: jax.Array, cfg: ArchConfig,
             lambda *cs: jnp.stack(cs, axis=0), *shared_caches)
         full_cache = {"layers": cache, "shared": shared}
     else:
-        x, cache = _run_stack(body, x, params["layers"], cfg)
-        full_cache = {"layers": cache}
+        full_cache = {}
+        for key, layer_cfg in _stacks(cfg):
+            x, full_cache[key] = _run_stack(body_for(layer_cfg), x,
+                                            params[key], cfg)
 
     logits = lm_logits(params["embedding"], x[:, -1:], cfg)
     return logits, full_cache
@@ -403,13 +475,12 @@ def _zamba_shared_window(max_len: int) -> Optional[int]:
 def init_cache(cfg: ArchConfig, batch: int, max_len: int, dtype=None):
     dtype = dtype or cdtype(cfg)
 
-    def one(_):
-        return block_init_cache(cfg, batch, max_len, dtype)
-
-    stacked = jax.tree_util.tree_map(
-        lambda x: jnp.zeros((cfg.n_layers,) + x.shape, x.dtype), one(None)
-    )
-    full = {"layers": stacked}
+    one = block_init_cache(cfg, batch, max_len, dtype)
+    k0 = cfg.first_k_dense
+    full = {key: jax.tree_util.tree_map(
+        lambda x, n=n: jnp.zeros((n,) + x.shape, x.dtype), one)
+        for key, n in (("dense_layers", k0), ("layers", cfg.n_layers - k0))
+        if n}
     if cfg.shared_attn_every:
         window = cfg.window or _zamba_shared_window(max_len)
         sc = _shared_attn_cfg(cfg)
@@ -427,10 +498,14 @@ def decode_step(params: Dict, tokens: jax.Array, cache, pos: jax.Array,
     """tokens (B,1[,Q]), pos (B,) -> (logits (B,1,V[,Q]), new cache)."""
     x = embed_tokens(params["embedding"], tokens, cfg)
 
-    def body(x, xs):
-        layer_p, layer_cache = xs
-        x, new_cache = block_decode(layer_p, x, cfg, layer_cache, pos)
-        return x, new_cache
+    def body_for(layer_cfg):
+        def body(x, xs):
+            layer_p, layer_cache = xs
+            return block_decode(layer_p, x, layer_cfg, layer_cache, pos)
+
+        return body
+
+    body = body_for(cfg)
 
     if cfg.shared_attn_every:
         every = cfg.shared_attn_every
@@ -458,8 +533,10 @@ def decode_step(params: Dict, tokens: jax.Array, cache, pos: jax.Array,
                 lambda *cs: jnp.stack(cs, axis=0), *new_shared),
         }
     else:
-        x, nc = _run_stack(body, x, (params["layers"], cache["layers"]), cfg)
-        new_cache = {"layers": nc}
+        new_cache = {}
+        for key, layer_cfg in _stacks(cfg):
+            x, new_cache[key] = _run_stack(body_for(layer_cfg), x,
+                                           (params[key], cache[key]), cfg)
 
     logits = lm_logits(params["embedding"], x, cfg)
     return logits, new_cache

@@ -30,6 +30,12 @@ class ModelBundle:
     def loss(self, params, batch) -> Tuple[jax.Array, Dict]:
         return B.loss_fn(params, batch, self.cfg)
 
+    def state_mask(self, params) -> Dict:
+        return B.state_mask(params)
+
+    def after_step(self, params, metrics) -> Tuple[Dict, Dict]:
+        return B.after_step(params, metrics, self.cfg)
+
     def forward(self, params, tokens):
         return B.forward(params, tokens, self.cfg)
 

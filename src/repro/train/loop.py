@@ -20,13 +20,14 @@ Fault-tolerance behaviour:
 from __future__ import annotations
 
 from collections import deque
-from dataclasses import dataclass
-from typing import Callable, Dict, List, Optional
+from dataclasses import dataclass, field
+from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import numpy as np
 
 from repro.ckpt import CheckpointManager
+from repro.core import stats
 from repro.core.stats import span
 from repro.models.registry import ModelBundle
 from repro.pipeline import PackedLoader
@@ -56,6 +57,11 @@ class StepEvent:
     loss: float
     wall_s: float
     straggler: bool = False
+    #: the step's metrics beyond the loss: ``ce`` and ``aux``, and a MoE
+    #: model's counters ``moe.rows_local``, ``moe.load_max_over_mean``,
+    #: ``moe.bias_abs_mean`` and the per-layer picks per expert
+    #: ``moe.picks`` (an array)
+    counters: Dict[str, Any] = field(default_factory=dict)
 
 
 class TrainLoop:
@@ -154,7 +160,10 @@ class TrainLoop:
         """Run ``steps`` steps.  Each is the ``train.step`` span (a
         ``StepTraceAnnotation`` numbered by the step it completes), with
         ``train.next_batch``, ``train.place``, ``train.dispatch``,
-        ``train.loss_wait`` and ``train.bookkeeping`` inside it."""
+        ``train.loss_wait`` and ``train.bookkeeping`` inside it.  Every
+        metric the step returns beyond the loss is fetched with it and
+        kept in the step's :class:`StepEvent` and, while a profiler
+        session is active, in a ``train.counters`` record."""
         steps = steps if steps is not None else self.config.steps
         batches = self.loader.batches()
         target = self.step + steps
@@ -170,14 +179,19 @@ class TrainLoop:
                      metrics) = self._step_fn(self.params, self.opt_state,
                                               self.err_state, jb)
                 with span("train.loss_wait", k, timed=True) as lw:
-                    loss = float(metrics["loss"])
+                    got = jax.device_get(metrics)
+                    loss = float(got.pop("loss"))
+                    counters = {n: float(x) if np.ndim(x) == 0 else x
+                                for n, x in got.items()}
                 self.step = k
                 with span("train.bookkeeping", k):
-                    self._bookkeep(loss, (lw.t1 - st.t0) / 1e9)
+                    if counters:
+                        stats.note("train.counters", (k, counters))
+                    self._bookkeep(loss, (lw.t1 - st.t0) / 1e9, counters)
         self.mgr.wait()
         return self.history
 
-    def _bookkeep(self, loss: float, wall: float) -> None:
+    def _bookkeep(self, loss: float, wall: float, counters: Dict) -> None:
         """Straggler check, history, logging and checkpointing after a
         step whose input, placement, dispatch and loss took ``wall``."""
         straggler = False
@@ -185,7 +199,7 @@ class TrainLoop:
             med = float(np.median(self._step_times))
             straggler = wall > self.config.straggler_factor * med
         self._step_times.append(wall)
-        ev = StepEvent(self.step, loss, wall, straggler)
+        ev = StepEvent(self.step, loss, wall, straggler, counters)
         self.history.append(ev)
         if straggler and self.on_straggler:
             self.on_straggler(ev)

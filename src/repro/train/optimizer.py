@@ -37,7 +37,11 @@ class AdamW:
         )
         return AdamWState(jnp.zeros((), jnp.int32), zeros(params), zeros(params))
 
-    def update(self, grads, state: AdamWState, params) -> Tuple[Dict, AdamWState]:
+    def update(self, grads, state: AdamWState, params,
+               frozen=None) -> Tuple[Dict, AdamWState]:
+        """One step.  ``frozen``, a tree of bools like ``params`` (or
+        None), marks the leaves that are the model's state, not trained:
+        they are returned as they are, never updated nor decayed."""
         step = state.step + 1
         gf = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32), grads)
         if self.clip_norm is not None:
@@ -60,7 +64,11 @@ class AdamW:
                 u = u + self.weight_decay * p.astype(jnp.float32)
             return (p.astype(jnp.float32) - lr * u).astype(p.dtype)
 
-        new_params = jax.tree_util.tree_map(upd, params, m, v)
+        if frozen is None:
+            frozen = jax.tree_util.tree_map(lambda _: False, params)
+        new_params = jax.tree_util.tree_map(
+            lambda keep, p, mm, vv: p if keep else upd(p, mm, vv),
+            frozen, params, m, v)
         return new_params, AdamWState(step, m, v)
 
 

@@ -89,7 +89,9 @@ def make_train_step(
                     loss_fn, has_aux=True)(params, batch)
             if grad_compression:
                 grads, error_state = compress_grads(grads, error_state)
-            new_params, new_opt = opt.update(grads, opt_state, params)
+            new_params, new_opt = opt.update(grads, opt_state, params,
+                                             bundle.state_mask(params))
+            new_params, metrics = bundle.after_step(new_params, metrics)
         return new_params, new_opt, error_state, {
             "loss": loss.astype(jnp.float32), **{
                 k: v.astype(jnp.float32) for k, v in metrics.items()},

@@ -120,30 +120,35 @@ def _attn_grads(attn, q, k, v, w):
     return attn(q, k, v), jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
 
-@pytest.mark.parametrize("h,g,s,d", [
-    (3, 1, 256, 64), (4, 4, 256, 64), (6, 2, 256, 64),
-    (3, 1, 512, 64), (4, 4, 512, 64), (6, 2, 512, 64),
-    (6, 2, 256, 128),
+@pytest.mark.parametrize("h,g,s,d,dv", [
+    (3, 1, 256, 64, 64), (4, 4, 256, 64, 64), (6, 2, 256, 64, 64),
+    (3, 1, 512, 64, 64), (4, 4, 512, 64, 64), (6, 2, 512, 64, 64),
+    (6, 2, 256, 128, 128), (4, 4, 256, 192, 128),
 ], ids=["gqa3-256", "mha4-256", "gqa3x2-256", "gqa3-512", "mha4-512",
-        "gqa3x2-512", "gqa3x2-256-d128"])
+        "gqa3x2-512", "gqa3x2-256-d128", "mla-256-d192-v128"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-def test_flash_train_kernel_matches_ref_with_grads(h, g, s, d, dtype):
+def test_flash_train_kernel_matches_ref_with_grads(h, g, s, d, dv, dtype):
     """Output and dq, dk, dv of the train kernel (interpret mode) against
     the XLA attention in float32; bf16 inputs round q, k, v, p and the
-    outputs to bf16, hence the looser bound."""
+    outputs to bf16, hence the looser bound.  MLA's q/k head dim 192
+    (nope 128 + rope 64) with v head dim 128 among the shapes, at its
+    scale 1/sqrt(192)."""
     b = 1
     q = jnp.asarray(RNG.normal(0, 1, (b, h, s, d)), dtype=dtype)
     k = jnp.asarray(RNG.normal(0, 1, (b, g, s, d)), dtype=dtype)
-    v = jnp.asarray(RNG.normal(0, 1, (b, g, s, d)), dtype=dtype)
-    w = jnp.asarray(RNG.normal(0, 1, (b, h, s, d)), dtype=jnp.float32)
+    v = jnp.asarray(RNG.normal(0, 1, (b, g, s, dv)), dtype=dtype)
+    w = jnp.asarray(RNG.normal(0, 1, (b, h, s, dv)), dtype=jnp.float32)
+    scale = 1.0 / np.sqrt(d)
     out, grads = _attn_grads(
-        lambda q, k, v: flash_attention_train(q, k, v, interpret=True),
+        lambda q, k, v: flash_attention_train(q, k, v, scale=scale,
+                                              interpret=True),
         q, k, v, w)
     f32 = [x.astype(jnp.float32) for x in (q, k, v)]
     with jax.default_matmul_precision("highest"):
         want, want_grads = _attn_grads(
-            lambda q, k, v: ref.flash_attention_ref(q, k, v, causal=True),
+            lambda q, k, v: ref.flash_attention_ref(q, k, v, causal=True,
+                                                    scale=scale),
             *f32, w)
     assert out.dtype == dtype and [x.dtype for x in grads] == [dtype] * 3
     tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
@@ -176,6 +181,8 @@ DISPATCH = {
                     None, "xla"),
     "dv!=d": (((1, 4, 256, 96), (1, 4, 256, 96), (1, 4, 256, 64)), None,
               "xla"),
+    "mla-192-128": (((2, 16, 1024, 192),) * 2 + ((2, 16, 1024, 128),), None,
+                    "kernel"),
     "head-dim-80": (((1, 4, 256, 80),) * 3, None, "xla"),
     "window": (SMOLLM, 1024, "xla"),
 }

@@ -125,3 +125,56 @@ def test_train_step_attention_compiles(one_chip, no_cache, monkeypatch, grad):
     assert hlo.count(KERNEL) >= (2 if grad else 1)
     scores = f"{b},{cfg.n_heads},{s},{s}"
     assert scores not in hlo
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "gradient"])
+def test_mla_attention_compiles(one_chip, no_cache, monkeypatch, grad):
+    """Moonlight-16B-A3B's MLA attention as its train step calls it, at 2 x
+    8192 with q/k head dim 192 (nope 128 + rope 64) and v head dim 128:
+    on a TPU ``ops.flash_attention`` takes the train kernel, forward and
+    gradient, and no [2, 16, 8192, 8192] score tensor exists."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ops.ATTENTION, "calls",
+                        dict.fromkeys(ops.ATTENTION.calls, 0))
+    m = get_arch("moonlight-16b-a3b").mla
+    b, h, s = 2, 16, 8192
+    qk = jax.ShapeDtypeStruct((b, h, s, m.d_nope + m.d_rope), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((b, h, s, m.d_v), jnp.bfloat16, sharding=one_chip)
+    scale = 1.0 / (m.d_nope + m.d_rope) ** 0.5
+
+    def attn(q, k, v):
+        return ops.flash_attention(q, k, v, causal=True, scale=scale)
+
+    def loss(q, k, v):
+        return jnp.sum(attn(q, k, v).astype(jnp.float32))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else attn
+    hlo = _hlo(fn, qk, qk, v)
+    calls = ops.ATTENTION.calls
+    assert calls["xla"] == 0 and calls["kernel"] > 0
+    assert hlo.count(KERNEL) >= (2 if grad else 1)
+    assert f"{b},{h},{s},{s}" not in hlo
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "gradient"])
+def test_moe_grouped_matmul_compiles(one_chip, no_cache, monkeypatch, grad):
+    """The dropless MoE layer's grouped matmul (megablox gmm, and tgmm in
+    the weight gradient) at Moonlight's expert widths: 98304 rows (2 x 8192
+    tokens x top-6) over the 8 held experts of 2048 x 1408.  The backend
+    here is the CPU, so the test says it is a TPU (no interpret mode)."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    x = jax.ShapeDtypeStruct((98304, 2048), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((8, 2048, 1408), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+
+    def mm(x, w, sizes):
+        return ops.grouped_matmul(x, w, sizes)
+
+    def loss(x, w, sizes):
+        return jnp.sum(mm(x, w, sizes)[:1536].astype(jnp.float32))
+
+    fn = jax.grad(loss, argnums=(0, 1)) if grad else mm
+    hlo = _hlo(fn, x, w, sizes)
+    # the gradient: gmm for the rows, tgmm for the weights
+    assert hlo.count(KERNEL) >= (2 if grad else 1)

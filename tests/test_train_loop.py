@@ -1,12 +1,13 @@
 """Training loop: loss goes down, exact restart, stragglers, compression."""
 
 import time
+from dataclasses import replace
 
 import jax
 import numpy as np
 import pytest
 
-from repro.configs import get_arch
+from repro.configs import get_arch, smoke_config
 from repro.launch.mesh import make_local_mesh
 from repro.models import build
 from repro.pipeline import PackedLoader, ingest_corpus, synth_corpus
@@ -28,8 +29,8 @@ def corpus(tmp_path_factory):
     return p
 
 
-def make_loop(corpus, ckpt_dir, steps=20, **cfg_kw):
-    bundle = build(tiny_cfg())
+def make_loop(corpus, ckpt_dir, steps=20, arch=None, **cfg_kw):
+    bundle = build(arch or tiny_cfg())
     loader = PackedLoader(corpus, batch=4, seq_len=32)
     return TrainLoop(
         bundle, make_local_mesh(), loader, ckpt_dir,
@@ -101,3 +102,26 @@ def test_microbatched_matches_plain(corpus, tmp_path):
         np.testing.assert_allclose(np.asarray(x, np.float32),
                                    np.asarray(y, np.float32), atol=2e-2)
     assert abs(a.history[-1].loss - b.history[-1].loss) < 0.05
+
+
+def test_moe_step_counters_reach_the_history(corpus, tmp_path):
+    """A step's metrics beyond the loss reach its StepEvent: a MoE model's
+    counters, and a dense model's ``ce`` and ``aux``.  The MoE model is a
+    small Moonlight shape (1 dense + 3 MoE layers, 8 experts of which 4
+    held, top-2) over 4 x 32 tokens."""
+    cfg = smoke_config("moonlight-16b-a3b")
+    cfg = cfg.with_(vocab_size=256,
+                    moe=replace(cfg.moe, n_held=4, first_held=2))
+    hist = make_loop(corpus, str(tmp_path / "moe"), steps=3, arch=cfg).run()
+    layers, k, gamma = cfg.n_layers - cfg.first_k_dense, 2, cfg.moe.bias_update
+    for ev in hist:
+        picks = np.asarray(ev.counters["moe.picks"])
+        assert picks.shape == (layers, 8)
+        assert (picks.sum(-1) == 4 * 32 * k).all()
+        assert ev.counters["moe.rows_local"] == picks[:, 2:6].sum()
+        assert ev.counters["moe.load_max_over_mean"] >= 1.0
+        assert 0.0 < ev.counters["moe.bias_abs_mean"] <= ev.step * gamma + 1e-9
+    dense = make_loop(corpus, str(tmp_path / "dense"), steps=1).run()
+    assert set(dense[0].counters) == {"ce", "aux"}
+    assert dense[0].counters["ce"] == dense[0].loss
+    assert dense[0].counters["aux"] == 0.0
