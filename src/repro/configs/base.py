@@ -80,7 +80,12 @@ class ArchConfig:
     norm_eps: float = 1e-5
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
-    remat: bool = True                # activation checkpointing over layers
+    # activation checkpointing over layers.  Each layer stack keeps its
+    # layers' matmul outputs for the backward where, summed over all
+    # stacks, they fit the device's memory limit less the train state and
+    # a headroom; else, or where no limit can be read (the CPU), full
+    # remat (models/backbone._scan_layers).  False: no checkpoint at all
+    remat: bool = True
     scan_layers: bool = True          # False: python-unrolled (cost probes)
     attn_impl: str = "ref"            # "ref" | "chunked" (§Perf variant)
     # source provenance for the config values

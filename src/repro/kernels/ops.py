@@ -20,6 +20,7 @@ nothing on the write/read hot paths that only need the dispatch logic.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import sys
 import threading
@@ -187,6 +188,8 @@ class PathCounter:
         self._lock = threading.Lock()
 
     def count(self, path: str, key) -> None:
+        if getattr(_uncounted, "on", False):
+            return
         with self._lock:
             self.calls[path] += 1
         from ..core import stats
@@ -194,9 +197,43 @@ class PathCounter:
         stats.note(f"{self.name}.{path}", key)
 
 
+_uncounted = threading.local()
+
+
+@contextlib.contextmanager
+def uncounted():
+    """No :class:`PathCounter` counts on this thread inside the block: a
+    trace made only to measure (``models/backbone``'s remat probe) lowers
+    nothing."""
+    prev = getattr(_uncounted, "on", False)
+    _uncounted.on = True
+    try:
+        yield
+    finally:
+        _uncounted.on = prev
+
+
 #: the paths of :func:`flash_attention`: the train kernel, the
 #: forward-only kernel asked for with ``use_pallas=True``, the XLA attention
 ATTENTION = PathCounter("attention", ("kernel", "forward_kernel", "xla"))
+
+
+#: the activation-checkpoint policy each remat'd layer stack took
+#: (``models/backbone._scan_layers``): ``dots`` keeps its layers' matmul
+#: outputs for the backward, ``full`` keeps nothing inside a layer.  One
+#: count per stack lowered; the record's key holds the stack's shapes,
+#: the bytes the saving policy keeps and the budget they were held to
+REMAT = PathCounter("remat", ("dots", "full"))
+
+
+def device_memory_limit() -> Optional[int]:
+    """The bytes a program may hold on the first local device
+    (``memory_stats()["bytes_limit"]``); None where the backend reports
+    no limit (the CPU)."""
+    import jax
+
+    stats = jax.local_devices()[0].memory_stats()
+    return (stats or {}).get("bytes_limit")
 
 
 def _single_device() -> bool:

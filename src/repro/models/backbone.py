@@ -18,6 +18,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.core import Literal
 
 from repro.configs.base import ArchConfig
 from repro.distributed.sharding import shard
@@ -330,9 +331,90 @@ def _run_stack(body, carry, stacked, cfg: ArchConfig):
     return carry, ys
 
 
+#: the saving policy: a layer keeps the outputs of its matmuls (dot
+#: products without batch dimensions: projections, MLP, router and shared
+#: experts; not attention scores, not Pallas kernels) for the backward,
+#: which then recomputes only elementwise work (norms, rope, SiLU, adds)
+SAVE_DOTS = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+
+
+def _nbytes(tree) -> int:
+    """Bytes of the arrays (or shapes) in ``tree``."""
+    return sum(a.size * np.dtype(a.dtype).itemsize
+               for a in jax.tree_util.tree_leaves(tree))
+
+
+def _saved_bytes(body, carry, layer_p) -> int:
+    """Bytes one layer keeps for its backward under :data:`SAVE_DOTS`:
+    the residuals of ``jax.linearize`` through the checkpointed body that
+    are not the layer's parameters (held anyway) or constants, so its
+    input (which full remat keeps too) and its matmul outputs.  Traced
+    from shapes; nothing runs, and no path counter counts."""
+    # a function of its own: jax caches a checkpointed function's trace by
+    # the function, and the stack's own trace must not reuse this
+    # uncounted one
+    saving = jax.checkpoint(lambda c, p: body(c, p), policy=SAVE_DOTS,
+                            prevent_cse=False)
+
+    def residuals(carry, layer_p):
+        return jax.linearize(saving, carry, layer_p)[1]
+
+    with ops.uncounted():
+        jaxpr = jax.make_jaxpr(residuals)(carry, layer_p).jaxpr
+    n_carry = len(jax.tree_util.tree_leaves(carry))
+    held = set(jaxpr.invars[n_carry:]) | set(jaxpr.constvars)
+    return _nbytes([v.aval for v in jaxpr.outvars
+                    if not isinstance(v, Literal) and v not in held])
+
+
+def remat_budget(params, x, cfg: ArchConfig, limit: int) -> int:
+    """The bytes the layer stacks may keep for the backward on a device
+    of ``limit`` bytes, at the layers' input ``x``: the limit less the
+    train state (the parameters and their gradients in the parameters'
+    dtype, AdamW's two moments in f32) and less a headroom for what else
+    is live while a step runs: one more copy of the parameters (a
+    caller's snapshot: a checkpoint being written, a checker's start
+    point) and the loss head's logits with their gradient, in f32."""
+    n = sum(a.size for a in jax.tree_util.tree_leaves(params))
+    state = 2 * _nbytes(params) + 2 * 4 * n
+    logits = x.shape[0] * x.shape[1] * cfg.vocab_size * cfg.n_codebooks
+    return limit - state - _nbytes(params) - 2 * 4 * logits
+
+
+def _remat_policy(params, stacks, carry, cfg: ArchConfig):
+    """-> (policy, saved bytes, budget) for every remat'd stack of one
+    step: :data:`SAVE_DOTS` where the bytes it keeps, summed over every
+    stack's layers, fit :func:`remat_budget` of the device's memory
+    limit; otherwise None, full remat (nothing inside a layer kept).
+    Where no limit can be read (the CPU) the stacks keep full remat and
+    nothing is counted (saved and budget None).  Shapes are global: under
+    a mesh that shards them the count is too high, which errs toward full
+    remat."""
+    limit = ops.device_memory_limit()
+    if limit is None:
+        return None, None, None
+    budget = remat_budget(params, carry[0], cfg, limit)
+    saved = 0
+    for body, stacked in stacks:
+        n = jax.tree_util.tree_leaves(stacked)[0].shape[0]
+        layer_p = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), stacked)
+        saved += n * _saved_bytes(body, carry, layer_p)
+    return (SAVE_DOTS if saved <= budget else None), saved, budget
+
+
 def _scan_layers(params, x, cfg: ArchConfig, positions):
     """(x, total_aux, per-layer MoE stats of the last stack) after the
-    layer stacks."""
+    layer stacks.
+
+    With ``cfg.remat`` every stack's layer is a ``jax.checkpoint``; the
+    policy follows the device's memory (:func:`_remat_policy`): a layer
+    keeps its matmul outputs (:data:`SAVE_DOTS`) where all stacks' saved
+    outputs fit the memory limit less the train state and a headroom,
+    and keeps nothing (full remat: the backward runs the layer's forward
+    again) where they do not fit or no limit can be read.  ``ops.REMAT``
+    counts the policy of each stack lowered.  Without ``cfg.remat`` no
+    layer is checkpointed."""
 
     def body_for(layer_cfg):
         def body(carry, layer_p):
@@ -340,26 +422,43 @@ def _scan_layers(params, x, cfg: ArchConfig, positions):
             x, a, stats = block_apply(layer_p, x, layer_cfg, positions)
             return (x, aux + a), stats
 
-        return jax.checkpoint(body, prevent_cse=False) if cfg.remat else body
+        return body
 
-    body = body_for(cfg)
+    carry = (x, jnp.zeros((), jnp.float32))
+    if cfg.shared_attn_every:
+        stacks = [(body_for(cfg), params["layers"])]
+    else:
+        stacks = [(body_for(layer_cfg), params[key])
+                  for key, layer_cfg in _stacks(cfg)]
+    if cfg.remat:
+        policy, saved, budget = _remat_policy(params, stacks, carry, cfg)
+        path = "full" if policy is None else "dots"
+
+        def remat(body, stacked):
+            ops.REMAT.count(path, (
+                tuple(x.shape), jax.tree_util.tree_leaves(stacked)[0].shape[0],
+                _nbytes(stacked), saved, budget))
+            return jax.checkpoint(body, policy=policy, prevent_cse=False)
+
+        stacks = [(remat(body, stacked), stacked) for body, stacked in stacks]
 
     if cfg.shared_attn_every:
+        body = stacks[0][0]
         n_groups = cfg.n_layers // cfg.shared_attn_every
-        aux = jnp.zeros((), jnp.float32)
         for g in range(n_groups):
             group_p = jax.tree_util.tree_map(
                 lambda a, g=g: a[g * cfg.shared_attn_every:(g + 1) * cfg.shared_attn_every],
                 params["layers"],
             )
-            (x, aux), _ = _run_stack(body, (x, aux), group_p, cfg)
-            x = _shared_attn_apply(params["shared_attn"], x, cfg, positions)
+            carry, _ = _run_stack(body, carry, group_p, cfg)
+            carry = (_shared_attn_apply(params["shared_attn"], carry[0], cfg,
+                                        positions), carry[1])
+        x, aux = carry
         return x, aux, {}
 
-    carry = (x, jnp.zeros((), jnp.float32))
     stats = {}
-    for key, layer_cfg in _stacks(cfg):
-        carry, stats = _run_stack(body_for(layer_cfg), carry, params[key], cfg)
+    for body, stacked in stacks:
+        carry, stats = _run_stack(body, carry, stacked, cfg)
     x, aux = carry
     return x, aux, stats
 

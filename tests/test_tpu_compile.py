@@ -178,3 +178,66 @@ def test_moe_grouped_matmul_compiles(one_chip, no_cache, monkeypatch, grad):
     hlo = _hlo(fn, x, w, sizes)
     # the gradient: gmm for the rows, tgmm for the weights
     assert hlo.count(KERNEL) >= (2 if grad else 1)
+
+
+#: the compiler's HBM on a v5e, which it refuses to exceed
+V5E_HBM = int(15.75 * 2 ** 30)
+
+
+def _train_step_lowered(topo, cfg, b, s):
+    """The train step (``make_train_step``) lowered for one described chip
+    at batch ``b`` x ``s``."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.models.registry import build
+    from repro.train.optimizer import AdamWState
+    from repro.train.step import make_train_step
+
+    bundle = build(cfg)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    jitted, _ = make_train_step(bundle, mesh)
+    params = bundle.param_shapes()
+    f32 = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.float32), params)
+    opt = AdamWState(jax.ShapeDtypeStruct((), jnp.int32), f32, f32)
+    batch = {k: jax.ShapeDtypeStruct((b, s), jnp.int32)
+             for k in ("tokens", "labels")}
+    return jitted(batch).lower(params, opt,
+                               jax.ShapeDtypeStruct((), jnp.float32), batch)
+
+
+@pytest.fixture
+def v5e_memory(monkeypatch):
+    """The backend here is the CPU: say it is a TPU with a v5e's memory
+    limit, and count the remat policies from zero."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "device_memory_limit", lambda: V5E_HBM)
+    monkeypatch.setattr(ops.REMAT, "calls", dict.fromkeys(ops.REMAT.calls, 0))
+
+
+def test_smollm_train_step_saves_dots_and_fits(topo, no_cache, v5e_memory):
+    """smollm-360m's train step at 4 x 2048, as the train cell runs it
+    (head tied): the rule keeps the layers' matmul outputs, and the
+    compiled step, with one more copy of the parameters beside it (the
+    headroom's), fits the chip's memory."""
+    from repro.models.backbone import _nbytes
+    from repro.models.registry import build
+
+    cfg = get_arch("smollm-360m").with_(tie_embeddings=True)
+    compiled = _train_step_lowered(topo, cfg, 4, 2048).compile()
+    assert ops.REMAT.calls == {"dots": 1, "full": 0}
+    m = compiled.memory_analysis()
+    peak = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+    assert peak + _nbytes(build(cfg).param_shapes()) <= V5E_HBM
+
+
+def test_moonlight_train_step_keeps_full_remat(topo, no_cache, v5e_memory):
+    """Moonlight-16B-A3B's train cell (one chip's share, 2 x 8192): the
+    matmul outputs of its dense and MoE stacks do not fit beside the train
+    state, so both stacks keep full remat."""
+    from repro.configs.moonlight_16b_a3b import EIGHT_CHIP_SHARE
+
+    _train_step_lowered(topo, EIGHT_CHIP_SHARE, 2, 8192)
+    assert ops.REMAT.calls == {"dots": 0, "full": 2}
