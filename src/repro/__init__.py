@@ -11,7 +11,7 @@ Subpackages:
   skim        AGC-style dataset skimming application
   train       optimizer, train/serve steps, training loop
   distributed sharding rules, collectives, pipeline parallelism
-  launch      production mesh, multi-pod dry-run, train/serve entry points
+  launch      production mesh, train/serve entry points
 """
 
 __version__ = "1.0.0"

@@ -8,7 +8,7 @@ the per-arch files in this package.
 from __future__ import annotations
 
 from dataclasses import dataclass, field, replace
-from typing import Optional, Tuple
+from typing import Optional
 
 
 @dataclass(frozen=True)
@@ -84,9 +84,8 @@ class ArchConfig:
     # layers' matmul outputs for the backward where, summed over all
     # stacks, they fit the device's memory limit less the train state and
     # a headroom; else, or where no limit can be read (the CPU), full
-    # remat (models/backbone._scan_layers).  False: no checkpoint at all
+    # remat (models/backbone._run_layers).  False: no checkpoint at all
     remat: bool = True
-    scan_layers: bool = True          # False: python-unrolled (cost probes)
     attn_impl: str = "ref"            # "ref" | "chunked" (§Perf variant)
     # source provenance for the config values
     source: str = ""
@@ -95,14 +94,6 @@ class ArchConfig:
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
 
-    @property
-    def sub_quadratic(self) -> bool:
-        """Can this arch decode at 500k context with bounded memory?"""
-        if self.family in ("ssm",):
-            return True
-        if self.family == "hybrid":
-            return True  # shared-attn blocks run windowed at long context
-        return self.window is not None
 
     def with_(self, **kw) -> "ArchConfig":
         return replace(self, **kw)
@@ -154,13 +145,3 @@ class ShapeCell:
     @property
     def tokens(self) -> int:
         return self.seq_len * self.global_batch
-
-
-SHAPES: Tuple[ShapeCell, ...] = (
-    ShapeCell("train_4k", "train", 4_096, 256),
-    ShapeCell("prefill_32k", "prefill", 32_768, 32),
-    ShapeCell("decode_32k", "decode", 32_768, 128),
-    ShapeCell("long_500k", "decode", 524_288, 1),
-)
-
-SHAPES_BY_NAME = {s.name: s for s in SHAPES}

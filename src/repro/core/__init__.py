@@ -41,7 +41,6 @@ from .merge import BufferMerger, merge_files
 from .container import (
     Sink,
     FileSink,
-    AsyncFileSink,
     DevNullSink,
     LatencyModel,
     MemorySink,
@@ -96,7 +95,7 @@ __all__ = [
     "FillContext", "write_entries", "RNTJReader", "ReadOptions",
     "slice_entry_range",
     "F", "Expr", "Zone", "T_FALSE", "T_MAYBE", "T_TRUE", "required_columns",
-    "BufferMerger", "merge_files", "Sink", "FileSink", "AsyncFileSink",
+    "BufferMerger", "merge_files", "Sink", "FileSink",
     "DevNullSink", "LatencyModel", "MemorySink", "ThrottledSink",
     "close_all", "open_sink",
     "WriterStats", "ReaderStats", "CountingLock", "ColumnBuffer",

@@ -7,8 +7,6 @@ done by the writer's critical section) and *write bytes at an offset*
 
 Sinks:
   * :class:`FileSink`      — a real file, ``os.pwrite`` + optional fallocate.
-  * :class:`AsyncFileSink` — a FileSink advertising native ring submission
-    (io_uring via the thin liburing binding, DESIGN.md §6.7).
   * :class:`DevNullSink`   — infinitely fast storage (paper Fig. 2).
   * :class:`ThrottledSink` — bandwidth-limited wrapper to emulate the SSD /
     HDD of Figs. 3–4 on this container (token-bucket on write completion).
@@ -303,33 +301,6 @@ class FileSink(Sink):
         return True
 
 
-class AsyncFileSink(FileSink):
-    """A :class:`FileSink` that opts into **native ring submission**.
-
-    With write-behind enabled (``WriteOptions.io_inflight_bytes > 0``)
-    and ``io_ring`` in auto mode, the I/O engine submits this sink's
-    queued extents through an io_uring submission ring when the thin
-    ctypes/liburing binding loads (DESIGN.md §6.7) — batched kernel
-    submission instead of one completion thread call per write.  On
-    platforms without liburing the engine transparently uses its
-    emulated ring: same bytes, same accounting, same failure semantics.
-
-    Synchronous operations (header, footer, reads) behave exactly like
-    :class:`FileSink` — this class only *advertises* the capability via
-    :attr:`native_ring`; a subclass that overrides :meth:`pwrite` or
-    :meth:`pwritev` (fault injection, instrumentation) stops advertising
-    it, because a kernel ring would bypass the override.
-    """
-
-    @property
-    def native_ring(self) -> bool:
-        return (
-            type(self).pwrite is FileSink.pwrite
-            and type(self).pwritev is FileSink.pwritev
-            and self.fd >= 0
-        )
-
-
 class DevNullSink(Sink):
     """Tracks the file layout but discards bytes — the paper's /dev/null
     configuration isolates the software stack from storage bandwidth."""
@@ -573,13 +544,11 @@ class ThrottledSink(Sink):
         return self.inner.readable()
 
 
-def open_sink(path, create: bool = True, async_io: bool = False) -> Sink:
+def open_sink(path, create: bool = True) -> Sink:
     """Resolve a path-ish spec to a sink.
 
     ``/dev/null``/``devnull``/``null:`` → :class:`DevNullSink`; ``mem:``
-    → :class:`MemorySink`; an ``async:`` prefix (or ``async_io=True``)
-    → :class:`AsyncFileSink`, which lets the I/O engine use io_uring
-    ring submission when available; a ``scheme://bucket/key`` URL (e.g.
+    → :class:`MemorySink`; a ``scheme://bucket/key`` URL (e.g.
     ``mem-s3://bucket/file.rntj``, or ``s3://`` once a real transport is
     registered) → :class:`~repro.core.remote.ObjectStoreSink` over the
     scheme's registered transport (DESIGN.md §10); anything else →
@@ -593,8 +562,4 @@ def open_sink(path, create: bool = True, async_io: bool = False) -> Sink:
     if "://" in path:
         from .remote import open_remote_sink  # local import: no cycle
         return open_remote_sink(path, create=create)
-    if path.startswith("async:"):
-        return AsyncFileSink(path[len("async:"):], create=create)
-    if async_io:
-        return AsyncFileSink(path, create=create)
     return FileSink(path, create=create)

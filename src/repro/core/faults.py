@@ -28,14 +28,12 @@ device chaos and simulated object-store chaos alike.
 Because the base :class:`Sink.pwritev` decomposes vectored writes into one
 ``pwrite`` per part (and every concrete sink falls back to it when
 ``pwrite`` is overridden), this wrapper observes *every byte* of every
-engine path — monolithic, striped, write-behind, and ring submission all
+engine path — monolithic, striped and write-behind (ring submission) all
 funnel through here.  The same holds on the read side: the base
 ``pread_into`` copies through ``pread``, and :class:`FaultInjectingSink`
 additionally overrides ``pread_into`` itself so the reader's zero-copy
 staging path sees the schedule first-hand (torn reads fill a prefix into
 the caller's buffer before failing — exercising the stale-tail contract).
-A wrapped sink never advertises ``native_ring``, so the engine cannot
-bypass it through the kernel.
 
 Byte-count determinism: ``at_byte`` thresholds count bytes *persisted to
 the inner sink* (retried bytes count again).  With a single producer and

@@ -20,15 +20,10 @@ each individually optional:
   like a synchronous failed ``pwrite``, and ``drain()`` is the
   drain-before-footer barrier ``close()`` runs;
 * **ring submission** (DESIGN.md §6.7) — queued extents go onto a
-  **submission ring** instead of one executor future per stripe: an
-  io_uring ring through a thin ctypes/liburing binding when the library
-  loads and the sink is a real fd (``AsyncFileSink``), otherwise a
-  completion-thread + ``pwritev`` emulation whose observable behavior —
-  ``io_inflight_bytes`` accounting, poisoning, drain-before-footer
-  ordering, byte output — is identical on every platform.  A producer's
-  submit cost drops to one deque append + notify (``io_submit_ns``
-  counts it), and completions fold back through the same accounting as
-  the executor path.
+  **submission ring** (:class:`WriteBehindRing`) instead of one executor
+  future per stripe: producers append to a deque and completion threads
+  write each op through ``Sink.pwritev``.  A producer's submit cost is
+  one deque append + notify (``io_submit_ns`` counts it).
 
 The engine also closes the commit path's last allocation: with a
 :class:`~repro.core.bufpool.BufferPool` attached, an extent owner's
@@ -48,10 +43,7 @@ thread.
 
 from __future__ import annotations
 
-import ctypes
-import ctypes.util
 import errno
-import os
 import random
 import threading
 import time
@@ -59,8 +51,6 @@ from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Callable, List, Optional, Tuple
-
-import numpy as _np
 
 _ns = time.perf_counter_ns
 
@@ -71,11 +61,6 @@ DEFAULT_IO_WORKERS = 4
 
 FSYNC_ON_CLOSE = "on_close"
 FSYNC_EVERY_CLUSTER = "every_cluster"
-
-RING_AUTO = "auto"
-RING_EMULATED = "emulated"
-RING_URING = "uring"
-RING_OFF = "off"
 
 
 # ---------------------------------------------------------------------------
@@ -205,7 +190,7 @@ class _ExtentGroup:
 
 
 # ---------------------------------------------------------------------------
-# submission rings
+# the submission ring
 
 
 class _RingOp:
@@ -218,15 +203,14 @@ class _RingOp:
         self.nbytes = nbytes
 
 
-class EmulatedRing:
-    """Completion-thread + ``pwritev`` emulation of the submission ring.
+class WriteBehindRing:
+    """The write-behind submission ring: completion threads + ``pwritev``.
 
     Producers append ops under one condition variable (a deque append —
     no future allocation, no executor work-queue churn); ``workers``
     completion threads pop small batches and execute them through the
-    engine's normal job body, so accounting, poisoning and drain
-    semantics are *identical* to the io_uring backend and to the
-    executor path it replaces.
+    engine's job body (:meth:`IOEngine._run_job`), which owns accounting,
+    poisoning and drain semantics.
     """
 
     # ops claimed per lock acquisition: amortizes wakeups without letting
@@ -241,7 +225,7 @@ class EmulatedRing:
         self._workers = max(1, workers)
         # completion threads start lazily at the first submit, so a
         # writer that never enters write-behind (or a skim spawning many
-        # writers) pays no idle threads — matching the executor path
+        # writers) pays no idle threads
         self._threads: List[threading.Thread] = []
 
     def _ensure_threads(self) -> None:
@@ -289,364 +273,6 @@ class EmulatedRing:
             t.join()
 
 
-# -- io_uring (thin ctypes/liburing binding) --------------------------------
-#
-# Only engaged when (a) liburing.so loads, (b) the sink advertises a raw
-# fd with no pwrite override (AsyncFileSink), and (c) REPRO_IO_URING is
-# not "0".  The emulated ring above is the behavioral reference; this
-# backend must be observationally identical (same bytes, same poisoning,
-# same drain ordering) — it only changes *how* queued writes reach the
-# kernel: batched SQE submission, no thread per write.
-
-IORING_OP_WRITEV = 2
-_URING_DEPTH = 256
-
-
-class _IoUringSqe(ctypes.Structure):  # kernel UAPI, 64 bytes, stable
-    _fields_ = [
-        ("opcode", ctypes.c_uint8), ("flags", ctypes.c_uint8),
-        ("ioprio", ctypes.c_uint16), ("fd", ctypes.c_int32),
-        ("off", ctypes.c_uint64), ("addr", ctypes.c_uint64),
-        ("len", ctypes.c_uint32), ("rw_flags", ctypes.c_uint32),
-        ("user_data", ctypes.c_uint64), ("buf_index", ctypes.c_uint16),
-        ("personality", ctypes.c_uint16), ("splice_fd_in", ctypes.c_int32),
-        ("pad2", ctypes.c_uint64 * 2),
-    ]
-
-
-class _IoUringCqe(ctypes.Structure):  # kernel UAPI, stable
-    _fields_ = [
-        ("user_data", ctypes.c_uint64), ("res", ctypes.c_int32),
-        ("flags", ctypes.c_uint32),
-    ]
-
-
-class _IoUringSq(ctypes.Structure):  # liburing 2.x ABI
-    _fields_ = [
-        ("khead", ctypes.POINTER(ctypes.c_uint)),
-        ("ktail", ctypes.POINTER(ctypes.c_uint)),
-        ("kring_mask", ctypes.POINTER(ctypes.c_uint)),
-        ("kring_entries", ctypes.POINTER(ctypes.c_uint)),
-        ("kflags", ctypes.POINTER(ctypes.c_uint)),
-        ("kdropped", ctypes.POINTER(ctypes.c_uint)),
-        ("array", ctypes.POINTER(ctypes.c_uint)),
-        ("sqes", ctypes.POINTER(_IoUringSqe)),
-        ("sqe_head", ctypes.c_uint), ("sqe_tail", ctypes.c_uint),
-        ("ring_sz", ctypes.c_size_t), ("ring_ptr", ctypes.c_void_p),
-        ("pad", ctypes.c_uint * 4),
-    ]
-
-
-class _IoUringCq(ctypes.Structure):  # liburing 2.x ABI
-    _fields_ = [
-        ("khead", ctypes.POINTER(ctypes.c_uint)),
-        ("ktail", ctypes.POINTER(ctypes.c_uint)),
-        ("kring_mask", ctypes.POINTER(ctypes.c_uint)),
-        ("kring_entries", ctypes.POINTER(ctypes.c_uint)),
-        ("kflags", ctypes.POINTER(ctypes.c_uint)),
-        ("koverflow", ctypes.POINTER(ctypes.c_uint)),
-        ("cqes", ctypes.POINTER(_IoUringCqe)),
-        ("ring_sz", ctypes.c_size_t), ("ring_ptr", ctypes.c_void_p),
-        ("pad", ctypes.c_uint * 4),
-    ]
-
-
-class _IoUring(ctypes.Structure):
-    _fields_ = [
-        ("sq", _IoUringSq), ("cq", _IoUringCq),
-        ("flags", ctypes.c_uint), ("ring_fd", ctypes.c_int),
-        ("features", ctypes.c_uint), ("enter_ring_fd", ctypes.c_int),
-        ("int_flags", ctypes.c_uint8), ("pad", ctypes.c_uint8 * 3),
-        ("pad2", ctypes.c_uint),
-    ]
-
-
-class _Iovec(ctypes.Structure):
-    _fields_ = [("iov_base", ctypes.c_void_p), ("iov_len", ctypes.c_size_t)]
-
-
-_liburing = None          # loaded library handle; False once ruled out
-_liburing_lock = threading.Lock()
-
-
-def load_liburing():
-    """The liburing handle, or ``None`` when unavailable.
-
-    ``REPRO_IO_URING=0`` disables loading outright (the emulated ring is
-    then the only async backend); any load/symbol failure also resolves
-    to ``None`` — callers fall back, they never crash.
-    """
-    global _liburing
-    with _liburing_lock:
-        if _liburing is None:
-            if os.environ.get("REPRO_IO_URING", "").strip() == "0":
-                _liburing = False
-            else:
-                _liburing = _try_load_liburing() or False
-    return _liburing or None
-
-
-def _try_load_liburing():
-    name = ctypes.util.find_library("uring")
-    candidates = [name] if name else []
-    candidates += ["liburing.so.2", "liburing.so.1", "liburing.so"]
-    for cand in candidates:
-        if not cand:
-            continue
-        try:
-            lib = ctypes.CDLL(cand, use_errno=True)
-            for sym in ("io_uring_queue_init", "io_uring_get_sqe",
-                        "io_uring_submit", "__io_uring_get_cqe",
-                        "io_uring_queue_exit"):
-                getattr(lib, sym)
-            lib.io_uring_queue_init.restype = ctypes.c_int
-            lib.io_uring_submit.restype = ctypes.c_int
-            lib.io_uring_get_sqe.restype = ctypes.POINTER(_IoUringSqe)
-            lib.__io_uring_get_cqe.restype = ctypes.c_int
-            return lib
-        except (OSError, AttributeError):
-            continue
-    return None
-
-
-class UringRing:
-    """io_uring submission ring over a raw file descriptor.
-
-    One event-loop thread both flushes queued ops as batched SQEs (one
-    ``io_uring_submit`` syscall for a whole burst — the submission cost
-    the executor path paid per stripe) and reaps CQEs, folding each
-    completion back through the engine's normal accounting.  Buffers and
-    iovec arrays are pinned in ``_live`` from submit to completion.
-    """
-
-    def __init__(self, engine: "IOEngine", fd: int, lib=None,
-                 depth: int = _URING_DEPTH):
-        self._engine = engine
-        self._fd = fd
-        self._lib = lib or load_liburing()
-        if self._lib is None:
-            raise ValueError(
-                "io_uring requested but liburing is not loadable on this "
-                "platform (and REPRO_IO_URING may disable it); use the "
-                "emulated ring instead"
-            )
-        self._ring = _IoUring()
-        rc = self._lib.io_uring_queue_init(
-            ctypes.c_uint(depth), ctypes.byref(self._ring), ctypes.c_uint(0)
-        )
-        if rc < 0:
-            raise OSError(-rc, "io_uring_queue_init failed")
-        self._cv = threading.Condition()
-        self._ops: deque = deque()
-        self._stop = False
-        self._live = {}  # user_data -> (op, iovec array, pinned parts, t0)
-        self._next_id = 1
-        self._degraded = False  # submission broke: run ops synchronously
-        self._seen_fence = threading.Lock()  # memory fence for CQ-head store
-        self._thread = threading.Thread(
-            target=self._loop, daemon=True, name="rntj-uring"
-        )
-        self._thread.start()
-
-    def submit(self, group, off, parts, nbytes) -> None:
-        with self._cv:
-            self._ops.append(_RingOp(group, off, parts, nbytes))
-            self._cv.notify()
-
-    # -- event loop ----------------------------------------------------------
-
-    def _prep(self, op: _RingOp) -> bool:
-        sqe = self._lib.io_uring_get_sqe(ctypes.byref(self._ring))
-        if not sqe:
-            return False  # SQ full: flush + reap first
-        parts = [memoryview(p) for p in op.parts if len(p)]
-        iov = (_Iovec * max(1, len(parts)))()
-        # read-only views reject ctypes.from_buffer; a zero-copy numpy
-        # wrap exposes the address either way, and pinning the wrapper
-        # (plus the view it holds) keeps the bytes alive until the CQE
-        pinned = []
-        for i, mv in enumerate(parts):
-            arr = _np.frombuffer(mv, dtype=_np.uint8)
-            iov[i].iov_base = arr.ctypes.data
-            iov[i].iov_len = arr.nbytes
-            pinned.append(arr)
-        uid = self._next_id
-        self._next_id += 1
-        s = sqe.contents
-        s.opcode = IORING_OP_WRITEV
-        s.flags = 0
-        s.ioprio = 0
-        s.fd = self._fd
-        s.off = op.off
-        s.addr = ctypes.cast(iov, ctypes.c_void_p).value or 0
-        s.len = len(parts)
-        s.rw_flags = 0
-        s.user_data = uid
-        s.buf_index = 0
-        s.personality = 0
-        s.splice_fd_in = 0
-        self._live[uid] = (op, iov, pinned, self._engine._job_begin())
-        return True
-
-    def _reap(self, wait: bool) -> int:
-        cqe_pp = ctypes.POINTER(_IoUringCqe)()
-        rc = self._lib.__io_uring_get_cqe(
-            ctypes.byref(self._ring), ctypes.byref(cqe_pp),
-            ctypes.c_uint(0), ctypes.c_uint(1 if wait else 0), None,
-        )
-        if rc < 0 or not cqe_pp:
-            return 0
-        cqe = cqe_pp.contents
-        uid, res = cqe.user_data, cqe.res
-        # mark seen: advance the CQ head.  The store must not become
-        # visible before the field loads above (liburing uses a release
-        # store); pure ctypes has no atomics, so acquire/release a lock —
-        # a full fence on CPython — between the loads and the store.
-        with self._seen_fence:
-            self._ring.cq.khead.contents.value = (
-                self._ring.cq.khead.contents.value + 1
-            )
-        entry = self._live.pop(uid, None)
-        if entry is None:
-            return 1
-        op, _iov, _pinned, t0 = entry
-        err = None
-        if res > 0:
-            # the kernel wrote past the Sink API: account what landed so
-            # IOStats stays truthful on the native ring path too (a
-            # partial write's resumed tail is counted by sink.pwrite)
-            self._engine.sink._count_writev(1, res)
-        if res < 0:
-            err = OSError(-res, os.strerror(-res))
-            if self._engine.retry is not None and self._engine.retry.retryable(err):
-                # a retryable CQE error re-enters the engine's retrying
-                # write path synchronously (positioned rewrite: idempotent)
-                self._engine._count_retry()
-                try:
-                    self._engine._pwritev(op.off, op.parts)
-                    err = None
-                except BaseException as e:  # noqa: BLE001
-                    err = e
-        elif res != op.nbytes:
-            # a partial vectored write: finish it synchronously through
-            # the engine (correctness first; partials are rare here)
-            try:
-                self._engine._pwritev_resume(op.off, op.parts, res)
-            except BaseException as e:  # noqa: BLE001
-                err = e
-        self._engine._job_end(op.group, op.nbytes, t0, err)
-        return 1
-
-    def _submit_prepared(self) -> None:
-        """Flush prepared SQEs to the kernel.  On failure (the SQEs never
-        reached — or will never leave — the kernel, so no CQE will ever
-        arrive; silently dropping them would hang ``drain()`` forever)
-        the ring *degrades* instead of failing every in-flight extent:
-        :meth:`_fallback_execute` writes them out synchronously."""
-        rc = self._lib.io_uring_submit(ctypes.byref(self._ring))
-        if rc < 0:
-            self._fallback_execute(OSError(-rc, os.strerror(-rc)))
-
-    def _fallback_execute(self, err: OSError) -> None:
-        """Ring submission broke (DESIGN.md §8.2): execute every op still
-        in ``_live`` synchronously through the engine's retrying
-        ``_pwritev`` and fold the completions through ``_job_end``, then
-        stay degraded — future ops run the same way on this thread, like
-        a one-worker emulated ring.  Ops already submitted in an earlier
-        successful batch may still complete via CQE; a rewrite of the
-        same extent bytes is idempotent, and ``_reap`` ignores CQEs whose
-        op has already been folded."""
-        self._degraded = True
-        self._engine._note_ring_fallback(err)
-        for uid in list(self._live):
-            op, _iov, _pinned, t0 = self._live.pop(uid)
-            op_err = None
-            try:
-                self._engine._pwritev(op.off, op.parts)
-            except BaseException as e:  # noqa: BLE001
-                op_err = e
-            self._engine._job_end(op.group, op.nbytes, t0, op_err)
-
-    def _loop(self) -> None:
-        while True:
-            with self._cv:
-                while not self._ops and not self._stop and not self._live:
-                    self._cv.wait()
-                if self._stop and not self._ops and not self._live:
-                    return
-                batch = list(self._ops)
-                self._ops.clear()
-            for op in batch:
-                if self._degraded:
-                    self._engine._run_job(op.group, op.off, op.parts,
-                                          op.nbytes)
-                    continue
-                prepped = self._prep(op)
-                while not prepped and not self._degraded:
-                    # SQ full: flush prepared SQEs, then reap for room
-                    self._submit_prepared()
-                    if self._degraded:
-                        break
-                    self._reap(wait=True)
-                    prepped = self._prep(op)
-                if not prepped and self._degraded:
-                    # never made it into _live: run it directly
-                    self._engine._run_job(op.group, op.off, op.parts,
-                                          op.nbytes)
-            if batch and not self._degraded:
-                self._submit_prepared()
-            # reap whatever is ready; block only when nothing new can be
-            # submitted and completions are still owed
-            while self._reap(wait=False):
-                pass
-            if self._live:
-                with self._cv:
-                    if self._ops or self._stop:
-                        continue
-                self._reap(wait=True)
-
-    def close(self) -> None:
-        with self._cv:
-            self._stop = True
-            self._cv.notify_all()
-        self._thread.join()
-        self._lib.io_uring_queue_exit(ctypes.byref(self._ring))
-
-
-def make_ring(engine: "IOEngine", mode, workers: int):
-    """Resolve a ring ``mode`` to a backend (or ``None`` for the
-    executor path): ``"uring"`` requires the liburing binding + a native
-    async sink and raises otherwise; ``"auto"`` prefers io_uring when
-    both are available and falls back to the emulation; ``"emulated"``
-    forces the emulation; ``"off"``/falsy keeps the PR-4 executor
-    submission."""
-    if not mode or mode == RING_OFF:
-        return None
-    if mode is True:
-        mode = RING_AUTO
-    if mode not in (RING_AUTO, RING_EMULATED, RING_URING):
-        raise ValueError(f"unknown io_ring mode {mode!r}")
-    sink = engine.sink
-    fd = getattr(sink, "fd", None)
-    # only a sink that advertises native ring capability (AsyncFileSink:
-    # a real fd AND no pwrite override — instrumentation/fault-injection
-    # subclasses must keep seeing every byte) may bypass Sink.pwritev
-    native = bool(getattr(sink, "native_ring", False)) and isinstance(fd, int)
-    if mode == RING_URING:
-        if not native:
-            raise ValueError(
-                "io_ring='uring' needs an AsyncFileSink (a real fd with no "
-                "pwrite override)"
-            )
-        return UringRing(engine, fd)
-    if mode == RING_AUTO and native and load_liburing() is not None:
-        try:
-            return UringRing(engine, fd)
-        except (OSError, ValueError):
-            pass  # kernel without io_uring etc.: fall through to emulation
-    return EmulatedRing(engine, workers)
-
-
 # ---------------------------------------------------------------------------
 # the engine
 
@@ -658,12 +284,12 @@ class IOEngine:
     every commit path (buffered clusters, unbuffered pages, merge's raw
     cluster copies).  Synchronous mode writes on the calling thread
     (striped over the pool when configured) and returns the measured
-    io_ns; write-behind mode enqueues — onto the submission ring when one
-    is configured (``ring=``), else as executor jobs — and returns 0: the
-    workers add their io time to ``stats`` directly and report drained
-    bytes through ``on_drain`` (the rate-aware codec policy's bandwidth
-    signal).  ``buffer_pool`` receives an extent owner's recyclable
-    buffers when its last write lands.
+    io_ns; write-behind mode (``inflight_bytes > 0``) enqueues onto the
+    submission ring and returns 0: the ring's workers add their io time
+    to ``stats`` directly and report drained bytes through ``on_drain``
+    (the rate-aware codec policy's bandwidth signal).  ``buffer_pool``
+    receives an extent owner's recyclable buffers when its last write
+    lands.
     """
 
     def __init__(
@@ -676,7 +302,6 @@ class IOEngine:
         stats=None,
         on_error: Optional[Callable] = None,
         on_drain: Optional[Callable] = None,
-        ring=RING_OFF,
         buffer_pool=None,
         retry: Optional[RetryPolicy] = None,
     ):
@@ -697,17 +322,16 @@ class IOEngine:
         self.retries = 0             # retried operations (mirror of IOStats)
         self.giveups = 0             # operations that exhausted the budget
         self.stripe_fallbacks = 0    # striping disabled after stripe failure
-        self.ring_fallbacks = 0      # native ring degraded to synchronous
         self._stripe_disabled = False
         self._closed = False
         if not workers and (self.stripe_bytes > 0 or self.inflight_bytes > 0):
             workers = DEFAULT_IO_WORKERS
         self._workers = workers
-        # the submission ring exists only in write-behind mode; when it
-        # does, it owns all queued submissions and the executor would be
-        # dead weight — create one or the other, never both
+        # the submission ring exists only in write-behind mode and owns all
+        # queued submissions; the executor runs only the stripes of a
+        # synchronous striped write — create one or the other, never both
         self._ring = (
-            make_ring(self, ring, workers) if self.inflight_bytes > 0 else None
+            WriteBehindRing(self, workers) if self.inflight_bytes > 0 else None
         )
         self._pool = (
             ThreadPoolExecutor(max_workers=workers, thread_name_prefix="rntj-io")
@@ -740,14 +364,12 @@ class IOEngine:
     def async_mode(self) -> bool:
         """True when commits are queued (write-behind) instead of written
         on the committing thread."""
-        return self.inflight_bytes > 0 and (
-            self._pool is not None or self._ring is not None
-        )
+        return self._ring is not None
 
     @property
     def ring(self):
-        """The active submission ring backend, or ``None`` (executor
-        submission / synchronous mode)."""
+        """The write-behind submission ring, or ``None`` (synchronous
+        mode)."""
         return self._ring
 
     # -- backpressure ---------------------------------------------------------
@@ -796,8 +418,7 @@ class IOEngine:
     def _retrying(self, fn, *args):
         """Run ``fn(*args)`` under the engine's retry policy.  The single
         choke point every engine-issued write and fsync goes through:
-        sync, striped, emulated-ring, and uring-resume paths all call it
-        via :meth:`_pwritev`; CQE errors re-enter it via
+        the sync, striped and ring paths all call it via
         :meth:`_pwritev`.  The loop itself lives in :class:`Retrier` —
         shared with the reader's retrying preads and the remote sink's
         transport ops.  Without a policy it is a plain call."""
@@ -812,15 +433,6 @@ class IOEngine:
             self._stripe_disabled = True
         if self.stats is not None:
             self.stats.note_stripe_fallback()
-
-    def _note_ring_fallback(self, err: BaseException) -> None:
-        """The native submission ring can no longer submit: it degrades to
-        executing ops synchronously on its own thread (same bytes, same
-        accounting) rather than failing in-flight extents."""
-        with self._retry_mu:
-            self.ring_fallbacks += 1
-        if self.stats is not None:
-            self.stats.note_ring_fallback()
 
     # -- submission -----------------------------------------------------------
 
@@ -875,7 +487,7 @@ class IOEngine:
             if self._on_drain is not None:
                 self._on_drain(nbytes, io_ns)
             return io_ns
-        # write-behind: enqueue one job per stripe
+        # write-behind: enqueue one ring op per stripe
         if self._error is not None:
             # the writer is poisoned: drop the bytes (finalization will
             # refuse anyway) but keep the budget accounting balanced
@@ -888,12 +500,8 @@ class IOEngine:
         if self.stats is not None:
             for _ in stripes:
                 self.stats.note_io_job(self._inflight)
-        if self._ring is not None:
-            for s_off, s_parts, s_n in stripes:
-                self._ring.submit(group, s_off, s_parts, s_n)
-        else:
-            for s_off, s_parts, s_n in stripes:
-                self._pool.submit(self._run_job, group, s_off, s_parts, s_n)
+        for s_off, s_parts, s_n in stripes:
+            self._ring.submit(group, s_off, s_parts, s_n)
         if self.stats is not None:
             self.stats.add_io_submit_ns(_ns() - t0)
         return 0
@@ -938,39 +546,13 @@ class IOEngine:
         else:
             self.sink.pwritev(off, parts)
 
-    def _pwritev_resume(self, off: int, parts: List, written: int) -> None:
-        """Finish a partially completed vectored write from byte
-        ``written`` onward (io_uring short-write recovery).  Retried as a
-        whole — re-running the resume loop rewrites the same tail bytes."""
-        self._retrying(self._pwritev_resume_once, off, parts, written)
-
-    def _pwritev_resume_once(self, off: int, parts: List, written: int) -> None:
-        pos = 0
-        for p in parts:
-            mv = memoryview(p)
-            n = len(mv)
-            if written >= pos + n:
-                pos += n
-                continue
-            skip = max(0, written - pos)
-            self.sink.pwrite(off + pos + skip, mv[skip:])
-            pos += n
-
-    # -- job body (executor and ring workers share it) ------------------------
-
-    def _job_begin(self) -> int:
-        t0 = _ns()
-        with self._cv:
-            if self._running == 0:
-                self._busy_start = t0
-            self._running += 1
-        return t0
+    # -- job body (run by the ring's workers) ----------------------------------
 
     def _job_end(self, group: _ExtentGroup, nbytes: int, t0: int,
                  err: Optional[BaseException]) -> None:
-        """Completion fold shared by every async backend: stats, budget
-        release, busy-window drain reporting, last-stripe recycling +
-        fsync, poisoning."""
+        """Completion fold of one ring op: stats, budget release,
+        busy-window drain reporting, last-stripe recycling + fsync,
+        poisoning."""
         io_ns = _ns() - t0
         if err is not None:
             self._fail(err)
@@ -1007,7 +589,11 @@ class IOEngine:
 
     def _run_job(self, group: _ExtentGroup, off: int, parts: List,
                  nbytes: int) -> None:
-        t0 = self._job_begin()
+        t0 = _ns()
+        with self._cv:
+            if self._running == 0:
+                self._busy_start = t0
+            self._running += 1
         err = None
         try:
             if self._error is None:

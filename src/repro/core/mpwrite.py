@@ -101,14 +101,6 @@ class SharedExtentSink:
     def size(self) -> int:
         return self.inner.size
 
-    @property
-    def native_ring(self) -> bool:
-        return getattr(self.inner, "native_ring", False)
-
-    @property
-    def fd(self):  # pragma: no cover - only consulted by the native ring
-        return getattr(self.inner, "fd", -1)
-
     def pwrite(self, offset: int, data) -> None:
         self.inner.pwrite(offset, data)
 

@@ -181,7 +181,6 @@ class WriterStats:
     io_submit_ns: int = 0    # producer time spent submitting queued extents
     # -- fault handling / degradation (DESIGN.md §8.2) -----------------------
     io_stripe_fallbacks: int = 0  # striping disabled after a stripe failure
-    io_ring_fallbacks: int = 0    # native ring degraded to synchronous ops
     pool_hits: int = 0       # buffer-pool takes served from a size class
     pool_misses: int = 0     # buffer-pool takes that had to allocate
     pool_returns: int = 0    # buffers returned to the pool
@@ -248,7 +247,7 @@ class WriterStats:
 
     def add_io_submit_ns(self, ns: int) -> None:
         """Producer time spent handing a queued extent to the engine
-        (ring append / pool dispatch) — the submission overhead the async
+        (the ring append) — the submission overhead the async
         engine exists to shrink."""
         with self._mu:
             self.io_submit_ns += ns
@@ -264,10 +263,6 @@ class WriterStats:
     def note_stripe_fallback(self) -> None:
         with self._mu:
             self.io_stripe_fallbacks += 1
-
-    def note_ring_fallback(self) -> None:
-        with self._mu:
-            self.io_ring_fallbacks += 1
 
     def note_io_job(self, inflight: int) -> None:
         """One engine write job observed with ``inflight`` write-behind
@@ -334,7 +329,6 @@ class WriterStats:
             "io_hedge_wins": self.io.hedge_wins,
             "io_degradations": self.io.degradations,
             "io_stripe_fallbacks": self.io_stripe_fallbacks,
-            "io_ring_fallbacks": self.io_ring_fallbacks,
         }
 
 

@@ -32,12 +32,10 @@ I/O engine (DESIGN.md §6): every commit path funnels through one
 ``pwritev`` commits of un-assembled iovec plans (``scatter_commit``),
 striped parallel sub-extent writes (``io_stripe_bytes``), and bounded
 write-behind with producer backpressure (``io_inflight_bytes``), plus
-the fsync policy knob.  Queued extents submit through an async
-submission ring (``io_ring`` — io_uring on an ``AsyncFileSink`` when
-liburing loads, a behavior-identical emulation elsewhere, §6.7), and a
-writer-owned buffer pool (``buffer_pool_bytes``, §6.8) recycles
-detached scatter buffers on write completion, so the steady-state
-commit path allocates nothing.  ``close()`` drains the engine before
+the fsync policy knob.  Queued extents submit through the write-behind
+submission ring (§6.7), and a writer-owned buffer pool
+(``buffer_pool_bytes``, §6.8) recycles detached scatter buffers on write
+completion, so the steady-state commit path allocates nothing.  ``close()`` drains the engine before
 the footer is ever built, and engine write failures poison finalization
 through the same ``_commit_error`` latch as a synchronous failed
 ``pwrite``.  All knobs: DESIGN.md §7.1.
@@ -57,7 +55,7 @@ from . import compression as comp
 from .bufpool import Recyclable, make_pool as make_buffer_pool
 from .cluster import ClusterBuilder, SealedCluster
 from .container import Sink, open_sink
-from .ioengine import FSYNC_ON_CLOSE, RING_AUTO, IOEngine, RetryPolicy
+from .ioengine import FSYNC_ON_CLOSE, IOEngine, RetryPolicy
 from .metadata import (
     ANCHOR_SIZE,
     CLUSTER_ENV_SIZE,
@@ -128,13 +126,7 @@ class WriteOptions:
     io_workers: int = 0
     # "on_close" | "every_cluster" | int byte interval between fsyncs
     fsync_policy: object = FSYNC_ON_CLOSE
-    # -- async submission + buffer pool (DESIGN.md §6.7/§6.8) ----------------
-    # how queued (write-behind) extents are submitted: "auto" uses an
-    # io_uring submission ring when liburing loads and the sink is an
-    # AsyncFileSink, else the emulated completion-thread ring; "uring"
-    # requires the real ring; "emulated" forces the emulation; "off"
-    # keeps one executor job per stripe (the PR-4 path)
-    io_ring: object = RING_AUTO
+    # -- buffer pool (DESIGN.md §6.8) ----------------------------------------
     # residency bound of the writer's buffer pool, recycling detached
     # scatter buffers / scratch / merge copy buffers; 0 disables pooling
     buffer_pool_bytes: int = 64 * 1024 * 1024
@@ -246,7 +238,6 @@ class _WriterBase:
             on_drain=(
                 self._policy.observe_drain if self._policy is not None else None
             ),
-            ring=self.options.io_ring,
             buffer_pool=self._bufpool,
             retry=self.options.retry_policy,
         )

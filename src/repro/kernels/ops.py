@@ -219,7 +219,7 @@ ATTENTION = PathCounter("attention", ("kernel", "forward_kernel", "xla"))
 
 
 #: the activation-checkpoint policy each remat'd layer stack took
-#: (``models/backbone._scan_layers``): ``dots`` keeps its layers' matmul
+#: (``models/backbone._run_layers``): ``dots`` keeps its layers' matmul
 #: outputs for the backward, ``full`` keeps nothing inside a layer.  One
 #: count per stack lowered; the record's key holds the stack's shapes,
 #: the bytes the saving policy keeps and the budget they were held to

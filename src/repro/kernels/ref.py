@@ -105,7 +105,7 @@ def flash_attention_chunked(
     The §Perf optimization for the memory roofline term: never materializes
     the (Sq, Sk) score matrix — per-iteration footprint is (Sq, block).
     Mathematically identical to :func:`flash_attention_ref`; on TPU the
-    Pallas kernel replaces it, on CPU/dry-run this IS the compiled form.
+    Pallas kernel replaces it, on CPU this IS the compiled form.
     """
     b, h, sq, d = q.shape
     g, sk = k.shape[1], k.shape[2]

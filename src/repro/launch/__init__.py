@@ -1,4 +1,4 @@
-"""Launchers: production mesh, multi-pod dry-run, train/serve entries."""
+"""Launchers: production mesh, train/serve entries."""
 
 from .mesh import describe, make_local_mesh, make_production_mesh
 

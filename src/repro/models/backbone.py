@@ -305,32 +305,6 @@ def _shared_attn_prefill(p, x, cfg, positions, max_len, dtype, window):
 # forward passes
 
 
-def _layer_slice(tree, i):
-    return jax.tree_util.tree_map(lambda a: a[i], tree)
-
-
-def _run_stack(body, carry, stacked, cfg: ArchConfig):
-    """scan-over-layers, or a python unroll when cfg.scan_layers=False.
-
-    The unrolled form exists for the dry-run cost probes: XLA's
-    HloCostAnalysis counts a while-loop body ONCE regardless of trip
-    count, so true per-layer flops/bytes/collectives are extrapolated
-    from small unrolled compiles (launch/dryrun.py).
-    """
-    if cfg.scan_layers:
-        return jax.lax.scan(body, carry, stacked)
-    n = jax.tree_util.tree_leaves(stacked)[0].shape[0]
-    ys = []
-    for i in range(n):
-        carry, y = body(carry, _layer_slice(stacked, i))
-        ys.append(y)
-    if ys and ys[0] is not None:
-        ys = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *ys)
-    else:
-        ys = None
-    return carry, ys
-
-
 #: the saving policy: a layer keeps the outputs of its matmuls (dot
 #: products without batch dimensions: projections, MLP, router and shared
 #: experts; not attention scores, not Pallas kernels) for the backward,
@@ -403,7 +377,7 @@ def _remat_policy(params, stacks, carry, cfg: ArchConfig):
     return (SAVE_DOTS if saved <= budget else None), saved, budget
 
 
-def _scan_layers(params, x, cfg: ArchConfig, positions):
+def _run_layers(params, x, cfg: ArchConfig, positions):
     """(x, total_aux, per-layer MoE stats of the last stack) after the
     layer stacks.
 
@@ -450,7 +424,7 @@ def _scan_layers(params, x, cfg: ArchConfig, positions):
                 lambda a, g=g: a[g * cfg.shared_attn_every:(g + 1) * cfg.shared_attn_every],
                 params["layers"],
             )
-            carry, _ = _run_stack(body, carry, group_p, cfg)
+            carry, _ = jax.lax.scan(body, carry, group_p)
             carry = (_shared_attn_apply(params["shared_attn"], carry[0], cfg,
                                         positions), carry[1])
         x, aux = carry
@@ -458,7 +432,7 @@ def _scan_layers(params, x, cfg: ArchConfig, positions):
 
     stats = {}
     for body, stacked in stacks:
-        carry, stats = _run_stack(body, carry, stacked, cfg)
+        carry, stats = jax.lax.scan(body, carry, stacked)
     x, aux = carry
     return x, aux, stats
 
@@ -467,7 +441,7 @@ def _forward(params: Dict, tokens: jax.Array, cfg: ArchConfig):
     b, s = tokens.shape[:2]
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
     x = embed_tokens(params["embedding"], tokens, cfg)
-    x, aux, stats = _scan_layers(params, x, cfg, positions)
+    x, aux, stats = _run_layers(params, x, cfg, positions)
     return lm_logits(params["embedding"], x, cfg), aux, stats
 
 
@@ -545,7 +519,7 @@ def prefill(params: Dict, tokens: jax.Array, cfg: ArchConfig,
         for g in range(n_groups):
             group_p = jax.tree_util.tree_map(
                 lambda a, g=g: a[g * every:(g + 1) * every], params["layers"])
-            x, cache = _run_stack(body, x, group_p, cfg)
+            x, cache = jax.lax.scan(body, x, group_p)
             caches.append(cache)
             x, sc = _shared_attn_prefill(params["shared_attn"], x, cfg,
                                          positions, max_len, cache_dtype, window)
@@ -558,8 +532,8 @@ def prefill(params: Dict, tokens: jax.Array, cfg: ArchConfig,
     else:
         full_cache = {}
         for key, layer_cfg in _stacks(cfg):
-            x, full_cache[key] = _run_stack(body_for(layer_cfg), x,
-                                            params[key], cfg)
+            x, full_cache[key] = jax.lax.scan(body_for(layer_cfg), x,
+                                              params[key])
 
     logits = lm_logits(params["embedding"], x[:, -1:], cfg)
     return logits, full_cache
@@ -619,7 +593,7 @@ def decode_step(params: Dict, tokens: jax.Array, cache, pos: jax.Array,
                 lambda a, g=g: a[g * every:(g + 1) * every], params["layers"])
             gcache = jax.tree_util.tree_map(
                 lambda a, g=g: a[g * every:(g + 1) * every], cache["layers"])
-            x, nc = _run_stack(body, x, (group, gcache), cfg)
+            x, nc = jax.lax.scan(body, x, (group, gcache))
             new_layer_caches.append(nc)
             scache = jax.tree_util.tree_map(lambda a, g=g: a[g], cache["shared"])
             x, nsc = _shared_attn_decode(params["shared_attn"], x, cfg,
@@ -634,8 +608,8 @@ def decode_step(params: Dict, tokens: jax.Array, cache, pos: jax.Array,
     else:
         new_cache = {}
         for key, layer_cfg in _stacks(cfg):
-            x, new_cache[key] = _run_stack(body_for(layer_cfg), x,
-                                           (params[key], cache[key]), cfg)
+            x, new_cache[key] = jax.lax.scan(body_for(layer_cfg), x,
+                                             (params[key], cache[key]))
 
     logits = lm_logits(params["embedding"], x, cfg)
     return logits, new_cache

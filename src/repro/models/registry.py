@@ -1,21 +1,16 @@
-"""Model registry: ArchConfig -> ModelBundle (init/loss/prefill/decode).
-
-Also provides ``input_specs`` — ShapeDtypeStruct stand-ins for every model
-input of a given (arch x shape-cell), the pattern the multi-pod dry-run
-lowers against (no allocation).
-"""
+"""Model registry: ArchConfig -> ModelBundle (init/loss/prefill/decode)."""
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.archs import get_arch
-from repro.configs.base import ArchConfig, ShapeCell
+from repro.configs.base import ArchConfig
 
 from . import backbone as B
 
@@ -48,7 +43,7 @@ class ModelBundle:
     def decode_step(self, params, tokens, cache, pos):
         return B.decode_step(params, tokens, cache, pos, self.cfg)
 
-    # -- dry-run specs -------------------------------------------------------
+    # -- shapes ----------------------------------------------------------------
 
     def param_shapes(self):
         return jax.eval_shape(lambda: self.init(jax.random.PRNGKey(0)))
@@ -57,42 +52,6 @@ class ModelBundle:
         if self.cfg.n_codebooks > 1:
             return (batch, seq, self.cfg.n_codebooks)
         return (batch, seq)
-
-    def input_specs(self, cell: ShapeCell) -> Dict[str, Any]:
-        """ShapeDtypeStruct stand-ins for the cell's step function inputs.
-
-        The modality frontends of [vlm]/[audio] archs are stubs: specs are
-        precomputed token ids (chameleon VQ codes / EnCodec codebook codes).
-        """
-        i32 = jnp.int32
-        if cell.kind == "train":
-            return {
-                "tokens": jax.ShapeDtypeStruct(
-                    self._token_shape(cell.global_batch, cell.seq_len), i32),
-                "labels": jax.ShapeDtypeStruct(
-                    self._token_shape(cell.global_batch, cell.seq_len), i32),
-            }
-        if cell.kind == "prefill":
-            return {
-                "tokens": jax.ShapeDtypeStruct(
-                    self._token_shape(cell.global_batch, cell.seq_len), i32),
-            }
-        # decode: one new token against a cache of cell.seq_len
-        cache_shapes = jax.eval_shape(
-            lambda: self.init_cache(cell.global_batch, cell.seq_len)
-        )
-        return {
-            "tokens": jax.ShapeDtypeStruct(
-                self._token_shape(cell.global_batch, 1), i32),
-            "cache": cache_shapes,
-            "pos": jax.ShapeDtypeStruct((cell.global_batch,), i32),
-        }
-
-    def runnable(self, cell: ShapeCell) -> Tuple[bool, str]:
-        """Is this (arch x cell) runnable? long_500k needs sub-quadratic."""
-        if cell.name == "long_500k" and not self.cfg.sub_quadratic:
-            return False, "SKIP(full-attn): 500k dense decode cache unbounded"
-        return True, ""
 
 
 def build(cfg_or_name) -> ModelBundle:

@@ -1,8 +1,8 @@
 """AdamW optimizer + LR schedules (pure pytree implementation).
 
 Optimizer states share the parameter sharding (ZeRO: m/v are FSDP-sharded
-exactly like their parameters), so the dry-run memory analysis reflects a
-real sharded-optimizer training step.
+exactly like their parameters), so a compiled step's memory analysis
+reflects a real sharded-optimizer training step.
 """
 
 from __future__ import annotations

@@ -7,8 +7,6 @@ Produces jitted functions with explicit in/out shardings for a given mesh:
   * KV/state caches: generic [stack, dp, tp_kv, sp_kv, ...] pattern whose
     divisibility fallback picks head- or sequence-sharding per arch
   * donation: params/opt_state (train), cache (serve) — in-place buffers
-
-These are exactly the functions the multi-pod dry-run lowers and compiles.
 """
 
 from __future__ import annotations

@@ -1,10 +1,10 @@
-"""The seed reader's hot path, vendored verbatim for benchmarking.
+"""The seed reader's hot path, vendored verbatim as a test reference.
 
-This is the pre-ISSUE-2 read path as it stood before the read-engine
-rebuild: one ``pread`` per page, serial per-page decompress+decode
-(``read_page`` allocates per page), ``np.concatenate`` per column, no
-coalescing, no decode pool, no prefetch.  ``bench_reader.py`` measures
-the rebuilt engine against exactly this code.
+This is the read path as it stood before the read-engine rebuild: one
+``pread`` per page, serial per-page decompress+decode (``read_page``
+allocates per page), ``np.concatenate`` per column, no coalescing, no
+decode pool, no prefetch.  ``test_seed_compat.py`` checks that files
+written by every commit mode and codec still decode through it.
 """
 
 from __future__ import annotations

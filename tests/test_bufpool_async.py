@@ -1,8 +1,8 @@
-"""Buffer pool + async submission engine tests (ISSUE 5): size-class
-bounds, completion-driven recycling (a buffer is never handed out while
-a queued write still references it), ring submission byte-identity and
-ordering, poisoning through the emulated ring, and the pooled merge /
-unbuffered / reader paths."""
+"""Buffer pool + write-behind ring tests: size-class bounds,
+completion-driven recycling (a buffer is never handed out while a queued
+write still references it), ring submission byte-identity and ordering,
+poisoning through the ring, and the pooled merge / unbuffered / reader
+paths."""
 
 import threading
 import time
@@ -11,7 +11,6 @@ import numpy as np
 import pytest
 
 from repro.core import (
-    AsyncFileSink,
     BufferPool,
     Collection,
     ColumnBatch,
@@ -26,16 +25,9 @@ from repro.core import (
     ThrottledSink,
     WriteOptions,
     merge_files,
-    open_sink,
 )
 from repro.core.bufpool import Recyclable, _class_bytes
-from repro.core.ioengine import (
-    EmulatedRing,
-    IOEngine,
-    UringRing,
-    load_liburing,
-    make_ring,
-)
+from repro.core.ioengine import IOEngine
 
 
 def vec_schema():
@@ -175,7 +167,7 @@ def test_buffer_not_recycled_while_queued_write_references_it():
     pool = BufferPool(limit_bytes=1 << 22)
     sink = _GateSink()
     engine = IOEngine(sink, workers=1, inflight_bytes=1 << 20,
-                      ring="emulated", buffer_pool=pool)
+                      buffer_pool=pool)
     arr = pool.take(8192)
     memoryview(arr)[:5] = b"hello"
     owner = Recyclable([arr])
@@ -210,22 +202,11 @@ def test_sync_write_recycles_after_completion():
 def test_ring_write_behind_byte_identical_to_sync():
     a, b, c = MemorySink(), MemorySink(), MemorySink()
     write_file(a, WriteOptions(**BASE))  # synchronous reference
-    write_file(b, WriteOptions(**BASE, io_inflight_bytes=1 << 20,
-                               io_ring="emulated"))
+    write_file(b, WriteOptions(**BASE, io_inflight_bytes=1 << 20))
     write_file(c, WriteOptions(**BASE, io_inflight_bytes=1 << 20,
-                               io_ring="emulated", io_stripe_bytes=4096,
-                               pipelined_seal=True))
+                               io_stripe_bytes=4096, pipelined_seal=True))
     assert bytes(a.buf) == bytes(b.buf)
     assert bytes(a.buf) == bytes(c.buf)
-
-
-def test_ring_off_keeps_executor_path_identical():
-    a, b = MemorySink(), MemorySink()
-    write_file(a, WriteOptions(**BASE, io_inflight_bytes=1 << 20,
-                               io_ring="off"))
-    write_file(b, WriteOptions(**BASE, io_inflight_bytes=1 << 20,
-                               io_ring="emulated"))
-    assert bytes(a.buf) == bytes(b.buf)
 
 
 def test_ring_completion_ordering_vs_drain():
@@ -234,8 +215,7 @@ def test_ring_completion_ordering_vs_drain():
     sink = _GateSink()
     schema = vec_schema()
     rng = np.random.default_rng(3)
-    opts = WriteOptions(**BASE, io_inflight_bytes=4 << 20,
-                        io_ring="emulated", io_workers=2)
+    opts = WriteOptions(**BASE, io_inflight_bytes=4 << 20, io_workers=2)
     sink.gate.set()  # the header write (writer construction) may pass
     w = SequentialWriter(schema, sink, opts)
     w.fill_batch(make_batch(schema, rng, 2000))
@@ -276,7 +256,7 @@ def test_poisoning_through_emulated_ring():
     schema = vec_schema()
     rng = np.random.default_rng(5)
     w = SequentialWriter(schema, sink, WriteOptions(
-        **BASE, io_inflight_bytes=4 << 20, io_ring="emulated"))
+        **BASE, io_inflight_bytes=4 << 20))
     w.fill_batch(make_batch(schema, rng, 2000))
     with pytest.raises(RuntimeError, match="NOT finalized") as ei:
         w.flush_cluster()
@@ -293,8 +273,7 @@ def test_detached_buffers_survive_ring_write_behind_with_pool():
     schema = vec_schema()
     rng = np.random.default_rng(7)
     opts = WriteOptions(codec="none", cluster_bytes=1 << 16,
-                        io_inflight_bytes=4 << 20, io_ring="emulated",
-                        pipelined_seal=True)
+                        io_inflight_bytes=4 << 20, pipelined_seal=True)
     with SequentialWriter(schema, slow, opts) as w:
         for i in range(8):
             w.fill_batch(make_batch(schema, rng, 500, id0=i * 500))
@@ -310,68 +289,6 @@ def test_steady_state_detach_hits_the_pool():
     assert d["pool_hits"] > 0  # later clusters recycled earlier buffers
     r = RNTJReader(sink)
     np.testing.assert_array_equal(r.read_column("id"), np.arange(12000))
-
-
-# ---------------------------------------------------------------------------
-# io_uring loader and mode resolution
-
-
-def test_liburing_loader_is_graceful():
-    # on boxes without liburing this is None; with it, a handle — either
-    # way no exception escapes
-    lib = load_liburing()
-    assert lib is None or lib is not None
-
-
-def test_io_ring_uring_requires_async_sink(tmp_path):
-    sink = MemorySink()
-    engine = IOEngine(sink, workers=1, inflight_bytes=1)
-    with pytest.raises(ValueError, match="AsyncFileSink"):
-        make_ring(engine, "uring", 1)
-    engine.close()
-
-
-@pytest.mark.skipif(load_liburing() is not None,
-                    reason="liburing present: uring mode would succeed")
-def test_io_ring_uring_unavailable_raises_clear_error(tmp_path):
-    sink = AsyncFileSink(str(tmp_path / "f.rntj"))
-    try:
-        with pytest.raises(ValueError, match="liburing"):
-            IOEngine(sink, workers=1, inflight_bytes=1 << 20, ring="uring")
-    finally:
-        sink.close()
-
-
-@pytest.mark.skipif(load_liburing() is None, reason="needs liburing")
-def test_uring_ring_round_trip(tmp_path):
-    path = str(tmp_path / "f.rntj")
-    sink = AsyncFileSink(path)
-    stats = write_file(sink, WriteOptions(
-        **BASE, io_inflight_bytes=4 << 20, io_ring="uring"))
-    ref = MemorySink()
-    write_file(ref, WriteOptions(**BASE))
-    with open(path, "rb") as f:
-        assert f.read() == bytes(ref.buf)
-
-
-def test_async_open_sink_spellings(tmp_path):
-    a = open_sink(str(tmp_path / "a.rntj"), async_io=True)
-    b = open_sink("async:" + str(tmp_path / "b.rntj"))
-    try:
-        assert isinstance(a, AsyncFileSink) and isinstance(b, AsyncFileSink)
-        assert a.native_ring and b.native_ring
-    finally:
-        a.close()
-        b.close()
-
-
-def test_auto_ring_on_plain_sinks_is_emulated():
-    sink = MemorySink()
-    engine = IOEngine(sink, workers=1, inflight_bytes=1 << 20, ring="auto")
-    try:
-        assert isinstance(engine.ring, EmulatedRing)
-    finally:
-        engine.close()
 
 
 # ---------------------------------------------------------------------------
@@ -436,30 +353,6 @@ def test_base_pread_into_raises_on_short_read():
     buf = np.zeros(64, np.uint8)
     with pytest.raises(EOFError, match="short read"):
         ShortSink().pread_into(0, memoryview(buf))
-
-
-# ---------------------------------------------------------------------------
-# docs tooling promised by benchmarks/README.md
-
-
-def test_benchmarks_run_list_prints_documented_names():
-    import subprocess
-    import sys
-    from pathlib import Path
-
-    import os
-
-    repo = Path(__file__).resolve().parents[1]
-    env = dict(os.environ)
-    env["PYTHONPATH"] = str(repo / "src") + os.pathsep + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.run", "--list"],
-        cwd=repo, capture_output=True, text=True, timeout=120, env=env,
-    )
-    assert out.returncode == 0, out.stderr
-    for name in ("bench_writer", "bench_reader", "bench_codec", "bench_io",
-                 "fig2_devnull", "fig5_skim", "BENCH_io.json"):
-        assert name in out.stdout
 
 
 # ---------------------------------------------------------------------------

@@ -41,7 +41,6 @@ from repro.core import (
     scan_container,
 )
 from repro.core.faults import crashed_file_bytes, memory_sink_from_bytes
-from repro.core.ioengine import IOEngine, _ExtentGroup
 from repro.core import metadata as md
 from repro.core.pages import PageDesc
 
@@ -297,7 +296,7 @@ def test_write_behind_transient_errors_retried():
     entries = make_entries(400)
     fs = FaultInjectingSink(MemorySink(), [FaultSpec.transient_error(count=4)])
     opts = WriteOptions(cluster_bytes=1024, retry_policy=FAST,
-                        io_inflight_bytes=1 << 20, io_ring=0)
+                        io_inflight_bytes=1 << 20)
     w = ParallelWriter(SCHEMA, fs, opts)
     ctx = w.create_fill_context()
     for e in entries:
@@ -344,43 +343,6 @@ def test_fsync_transient_retried_permanent_poisons():
     d = w.stats.as_dict()
     assert d["io_fsync_failures"] >= 1
 
-
-def test_ring_fallback_executes_live_ops():
-    """UringRing._fallback_execute: a broken submission ring runs its
-    in-flight ops synchronously through the engine instead of failing
-    them (unit-level: the native ring needs liburing + a real fd)."""
-    from repro.core.ioengine import UringRing, _RingOp
-
-    sink = MemorySink()
-    engine = IOEngine(sink, workers=0, inflight_bytes=1 << 20,
-                      retry=FAST, ring="emulated")
-    try:
-        ring = UringRing.__new__(UringRing)
-        ring._engine = engine
-        ring._degraded = False
-        ring._live = {}
-        payload = b"R" * 512
-        off = sink.reserve(len(payload))
-        # mirror the submit path's accounting so _job_end balances
-        with engine._cv:
-            engine._inflight += len(payload)
-            engine._pending += 1
-        group = _ExtentGroup(1, len(payload), None, False)
-        op = _RingOp(group, off, [payload], len(payload))
-        ring._live[1] = (op, None, None, engine._job_begin())
-        ring._fallback_execute(OSError(errno.ENOMEM, "submit broke"))
-        assert ring._degraded
-        assert not ring._live
-        assert engine.ring_fallbacks == 1
-        assert sink.pread(off, len(payload)) == payload
-        engine.drain()                   # the group completed: no hang
-        assert engine.error is None
-    finally:
-        engine.close()
-
-
-# ---------------------------------------------------------------------------
-# journal format
 
 
 def test_cluster_envelope_roundtrip_and_corruption():
@@ -726,8 +688,7 @@ def test_crash_during_parallel_write_recovers_committed_prefix():
     byte-identical (the salvage count is whatever was durable)."""
     entries = make_entries(600, seed=5)
     fs = FaultInjectingSink(MemorySink(), [FaultSpec.kill_at(6000)])
-    opts = WriteOptions(cluster_bytes=1024, io_inflight_bytes=1 << 20,
-                        io_ring=0)
+    opts = WriteOptions(cluster_bytes=1024, io_inflight_bytes=1 << 20)
     w = ParallelWriter(SCHEMA, fs, opts)
     try:
         ctx = w.create_fill_context()

@@ -10,12 +10,12 @@ zero-copy ``pread_into`` path, and the reader-level retry chokepoint
 """
 
 import errno
-import sys
 import threading
-from pathlib import Path
 
 import numpy as np
 import pytest
+
+from _legacy_seed_reader import SeedRNTJReader
 
 from repro.core import (
     Collection,
@@ -49,7 +49,6 @@ from repro.core.remote import (
     salvage_remote,
 )
 
-REPO_ROOT = Path(__file__).resolve().parent.parent
 
 SCHEMA = Schema([
     Leaf("id", "int64"),
@@ -614,11 +613,6 @@ def test_acceptance_high_rtt_faulty_parallel_write():
     # with the sequential reference
     assert t.bucket.objects["k"]
 
-    sys.path.insert(0, str(REPO_ROOT / "benchmarks"))
-    try:
-        from _legacy_seed_reader import SeedRNTJReader
-    finally:
-        sys.path.pop(0)
     seed_r = SeedRNTJReader(
         ObjectStoreSink(FakeTransport(t.bucket), "k", create=False))
     assert seed_r.n_entries == len(entries)

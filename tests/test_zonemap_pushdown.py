@@ -11,7 +11,6 @@ with an explicit reason instead of serving unattested bounds, and the
 skim strategies produce byte-identical outputs pruned vs. unpruned.
 """
 
-import importlib.util
 import math
 import os
 import warnings
@@ -21,6 +20,7 @@ import numpy as np
 import pytest
 
 from _hypothesis_compat import given, settings, strategies as st
+from _legacy_seed_reader import SeedRNTJReader
 
 from repro.core import (
     Collection,
@@ -521,15 +521,9 @@ def test_backcompat_zone_maps_off_reads_unpruned_without_warnings():
 
 
 def test_forward_compat_seed_reader_reads_zonemapped_file(tmp_path):
-    spec = importlib.util.spec_from_file_location(
-        "_legacy_seed_reader",
-        Path(__file__).resolve().parent.parent
-        / "benchmarks" / "_legacy_seed_reader.py")
-    legacy = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(legacy)
     path = str(tmp_path / "zm.rntj")
     _flat_file(path, codec="zlib")
-    new, old = RNTJReader(path), legacy.SeedRNTJReader(path)
+    new, old = RNTJReader(path), SeedRNTJReader(path)
     try:
         assert old.n_clusters == len(new.clusters)
         for i in range(old.n_clusters):

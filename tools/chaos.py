@@ -15,7 +15,7 @@ asserts the robustness contract that DESIGN.md §8 promises for it:
                       is retried, the latter poisons (never swallowed).
 * ``stripe``        — a non-retryable stripe error: the engine rewrites
                       the extent monolithically and disables striping.
-* ``ring``          — write-behind (emulated ring) under transient
+* ``writebehind``   — write-behind (the submission ring) under transient
                       faults: completes with zero loss.
 * ``latency``       — injected latency spikes: slow but lossless.
 * ``kill``          — a matrix of process-kill points across the file:
@@ -222,12 +222,12 @@ def scenario_stripe(entries, seed):
     return {"stripe_fallbacks": d["io_stripe_fallbacks"]}
 
 
-def scenario_ring(entries, seed):
+def scenario_writebehind(entries, seed):
     fs = FaultInjectingSink(MemorySink(), [
         FaultSpec.transient_error(count=4),
     ])
     opts = WriteOptions(cluster_bytes=4096, retry_policy=POLICY,
-                        io_inflight_bytes=1 << 20, io_ring=0)
+                        io_inflight_bytes=1 << 20)
     w = ParallelWriter(SCHEMA, fs, opts)
     ctx = w.create_fill_context()
     for e in entries:
@@ -236,7 +236,7 @@ def scenario_ring(entries, seed):
     w.close()
     d = w.stats.as_dict()
     assert d["io_retries"] >= 1
-    verify_lossless(fs.inner, entries, "ring")
+    verify_lossless(fs.inner, entries, "writebehind")
     return {"retries": d["io_retries"]}
 
 
@@ -688,7 +688,7 @@ SCENARIOS = {
     "enospc": scenario_enospc,
     "fsync": scenario_fsync,
     "stripe": scenario_stripe,
-    "ring": scenario_ring,
+    "writebehind": scenario_writebehind,
     "latency": scenario_latency,
     "kill": scenario_kill,
     "skim": scenario_skim,

@@ -22,7 +22,6 @@ DOCS = [
     "PAPER.md",
     "PAPERS.md",
     "CHANGES.md",
-    "benchmarks/README.md",
 ]
 
 _LINK = re.compile(r"\[[^\]]*\]\(([^)\s]+)\)")
